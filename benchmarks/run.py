@@ -215,6 +215,9 @@ def _print_rows(rows: list) -> None:
 def main() -> None:
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     steps = 0
     smoke = "--smoke" in sys.argv[1:]
     for a in sys.argv[1:]:
@@ -231,7 +234,7 @@ def main() -> None:
         # rejoin recovery) + the observability-overhead lane (tracing-on vs
         # tracing-off, asserting the <= 3% throughput budget and span/export
         # well-formedness) + the sharded mesh-replica lane (forced-host-device
-        # subprocess asserting bitwise parity of sharded vs single-device
+        # subprocess asserting parity of sharded vs single-device
         # responses) + the adaptive control-plane lane (feedback-tuned knobs
         # vs static defaults, asserting convergence with logged evidence,
         # bitwise parity across the live reconfiguration, the adapted-beats-
@@ -255,13 +258,10 @@ def main() -> None:
         ("benchmarks.fig12a_accuracy", {"steps": steps}),
         ("benchmarks.roofline", {}),
     ]:
-        try:
-            mod = importlib.import_module(mod_name)
-            for row in mod.run(**kwargs):
-                claim = f" (claim: {row['claim']})" if row.get("claim") else ""
-                print(f"{row['name']},,{row['value']:.6g}{claim}")
-        except Exception as e:  # noqa: BLE001
-            print(f"{mod_name},,ERROR {type(e).__name__}: {e}")
+        mod = importlib.import_module(mod_name)
+        for row in mod.run(**kwargs):
+            claim = f" (claim: {row['claim']})" if row.get("claim") else ""
+            print(f"{row['name']},,{row['value']:.6g}{claim}")
     for row in microbench():
         print(f"{row['name']},{row['us']:.1f},")
     for row in engine_bench():

@@ -742,9 +742,14 @@ for mode in (None, "batch", "tensor"):
             lats.append(time.perf_counter() - t_sub)
     wall = time.perf_counter() - t0
     for o, r in zip(outs, refs):
-        assert np.array_equal(o, r), (
-            f"serve_shard: sharding={mode} response != single-device bits"
-        )
+        # fp32 tensor mode's column blocks are dots of another width, which
+        # the CPU backend sums in another order (see
+        # tests/test_sharded_replica.py); the other modes are bitwise
+        if mode == "tensor":
+            ok = np.abs(o - r).max() <= 1e-4 * np.abs(r).max()
+        else:
+            ok = np.array_equal(o, r)
+        assert ok, f"serve_shard: sharding={mode} response != single-device"
     n_rep = len(rt.pool.replicas)
     tag = mode or "unsharded"
     rows.append({
@@ -752,7 +757,7 @@ for mode in (None, "batch", "tensor"):
         "us": float(np.percentile(lats, 95)) * 1e6,
         "note": (
             f"{len(outs) / wall:.1f} req/s over {n_rep}x{per}-device replicas"
-            f" (forced host devices); parity bitwise-ok"
+            f" (forced host devices); parity ok"
         ),
     })
 
@@ -766,9 +771,10 @@ def run_shard(smoke: bool = False, seed: int = 0) -> list[dict]:
 
     Runs in a subprocess with ``xla_force_host_platform_device_count=4``
     (the parent process must keep its single-device view) and SELF-ASSERTS
-    bitwise parity of every sharded response against the single-device
-    reference before any throughput number is reported — a parity break
-    fails the lane, not just a dashboard.
+    parity of every sharded response against the single-device reference
+    (bitwise, and within 1e-4 x max|logit| for tensor mode) before any
+    throughput number is reported — a parity break fails the lane, not just
+    a dashboard.
 
     Forced host devices timeshare one CPU, so the throughput columns here
     measure dispatch/overhead plumbing, not real multi-chip scaling.
@@ -784,7 +790,14 @@ def run_shard(smoke: bool = False, seed: int = 0) -> list[dict]:
     with tempfile.TemporaryDirectory() as td:
         out = os.path.join(td, "rows.json")
         env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        # CPU-only by design: forced host devices exist only on the CPU
+        # backend, and this parent may hold the accelerator.  Single-threaded
+        # Eigen keeps a 1-row shard's matmul sums in full-batch order.
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (
+            "--xla_force_host_platform_device_count=4"
+            " --xla_cpu_multi_thread_eigen=false"
+        )
         env["PYTHONPATH"] = "src"
         env["PC2IM_SHARD_OUT"] = out
         env["PC2IM_SHARD_SMOKE"] = str(int(smoke))

@@ -29,14 +29,14 @@ print(f"grid  : {grid.n_tiles} tiles x {grid.tile_size} cap, utilisation {float(
 
 # --- C1+C3: in-VMEM tiled L1 FPS (the APD-CIM/Ping-Pong-MAX kernel) ---------
 tiled = jnp.take(pts, msp.tiles, axis=0)  # (8, 256, 3) zero padding
-idx_kernel = fps_tiles(tiled, 64, metric="l1", backend="pallas", interpret=True)
+idx_kernel = fps_tiles(tiled, 64, metric="l1", backend="pallas")
 idx_xla = fps_tiles(tiled, 64, metric="l1", backend="xla")
 print(f"tiled FPS kernel == oracle: {bool((idx_kernel == idx_xla).all())}")
 
 # --- C1: fused lattice query -------------------------------------------------
 centroids = jnp.take(pts, jnp.take(msp.tiles[0], idx_kernel[0]), axis=0)
 nbrs = lattice_query_fused(pts, centroids, radius=0.3, nsample=16,
-                           backend="pallas", interpret=True)
+                           backend="pallas")
 print(f"lattice query: fill-rate {float(nbrs.mask.mean()):.2f} (L = 1.6R)")
 
 # --- the batched PreprocessEngine (B clouds -> ONE kernel grid) --------------

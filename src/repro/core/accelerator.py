@@ -31,7 +31,6 @@ import dataclasses
 import threading
 
 import jax
-from jax.experimental.shard_map import shard_map
 
 from repro.core.policy import ExecutionPolicy, resolve_policy
 from repro.launch.mesh import make_replica_mesh
@@ -331,10 +330,12 @@ class MeshArtifacts:
         replicated logits.
 
     Both modes are bitwise-equal to the accelerator's single-device
-    `infer` on the same batch (pinned by tests/test_sharded_replica.py).
-    `check_rep=False` matches the repo's shard_map precedent
+    `infer` on the same batch on the TPU (`chip_smoke.py --chips 4`); on
+    the CPU backend fp32 tensor mode is held to 1e-4 x max|logit| instead
+    (tests/test_sharded_replica.py).
+    `check_vma=False` matches the repo's shard_map precedent
     (parallel/pipeline.py) — the tensor mode's gathered intermediates are
-    replicated values the replication checker can't see through.
+    replicated values the varying-axes checker can't see through.
     """
 
     def __init__(self, accel: PC2IMAccelerator, devices):
@@ -362,12 +363,12 @@ class MeshArtifacts:
             return jax.lax.dynamic_slice_in_dim(logits, idx * rows, rows, axis=0)
 
         self._infer = jax.jit(
-            shard_map(
+            jax.shard_map(
                 mapped,
                 mesh=self.mesh,
                 in_specs=(p_params, p_points),
                 out_specs=p_logits,
-                check_rep=False,
+                check_vma=False,
             )
         )
 

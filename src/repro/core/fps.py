@@ -32,6 +32,19 @@ Metric = Literal["l1", "l2"]
 _BIG = jnp.float32(1e30)
 
 
+def axis_distance(dx: jax.Array, dy: jax.Array, dz: jax.Array, metric: Metric = "l2") -> jax.Array:
+    """Distance from per-axis differences, summed in the fixed order (x + y) + z.
+
+    Every distance in the repo goes through here: the Pallas kernels sum
+    coordinate rows and the XLA paths a trailing axis, and spelling the
+    order out is what makes their float results bitwise-equal on every
+    backend (a reduce over the 3-axis may associate differently).
+    """
+    if metric == "l1":
+        return (jnp.abs(dx) + jnp.abs(dy)) + jnp.abs(dz)
+    return (dx * dx + dy * dy) + dz * dz
+
+
 def pairwise_distance(a: jax.Array, b: jax.Array, metric: Metric = "l2") -> jax.Array:
     """Distance matrix between point sets.  a: (N, 3), b: (M, 3) -> (N, M).
 
@@ -39,17 +52,13 @@ def pairwise_distance(a: jax.Array, b: jax.Array, metric: Metric = "l2") -> jax.
     L1 returns the Manhattan distance (paper eq. 2).
     """
     diff = a[:, None, :] - b[None, :, :]
-    if metric == "l1":
-        return jnp.sum(jnp.abs(diff), axis=-1)
-    return jnp.sum(diff * diff, axis=-1)
+    return axis_distance(diff[..., 0], diff[..., 1], diff[..., 2], metric)
 
 
 def point_distance(points: jax.Array, ref: jax.Array, metric: Metric = "l2") -> jax.Array:
     """Distance of every point to a single reference point.  (N, 3), (3,) -> (N,)."""
     diff = points - ref[None, :]
-    if metric == "l1":
-        return jnp.sum(jnp.abs(diff), axis=-1)
-    return jnp.sum(diff * diff, axis=-1)
+    return axis_distance(diff[..., 0], diff[..., 1], diff[..., 2], metric)
 
 
 def fused_fps_step(

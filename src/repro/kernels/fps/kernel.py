@@ -12,11 +12,17 @@ Hardware mapping (paper -> TPU v5e):
       all P lane-parallel distances per iteration; the K-step loop is a
       lax.fori_loop INSIDE the kernel, so nothing round-trips to HBM.
 
-Layout choices (TPU-native):
+Layout choices (TPU-native, all accepted by Mosaic):
   * points as (3, P) with P a multiple of 128 — coordinates on the sublane
     axis, points on the lane axis, so |x - x_ref| is a full-width VPU op.
   * dmin scratch as (1, P) f32.
-  * argmax via iota+select (Mosaic-safe; avoids 1D argmax lowering).
+  * every loop value stays a vector: each coordinate row of the reference
+    point is read with a one-hot lane mask + lane sum (exact: one nonzero
+    term), argmax is iota+select, and the sampled indices collect in a
+    (1, k) vector that is stored once — Mosaic has no lane-axis
+    dynamic_slice and no scalar store to VMEM.
+  * output (T, 1, k) with block (None, 1, k): the block's last two dims
+    equal the array's, which satisfies the (8, 128) tiling rule for any k.
 
 Grid: one program per tile -> batched FPS over (T, 3, P) with zero padding
 (equal-size MSP tiles map 1:1 onto grid steps — the C2 utilisation story).
@@ -31,35 +37,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.fps import axis_distance
+
 _BIG = 1e30
 
 
 def _fps_kernel(points_ref, out_idx_ref, dmin_ref, *, k: int, metric: str):
-    """One tile: points_ref (1, 3, P) f32 -> out_idx_ref (1, k) int32."""
+    """One tile: points_ref (3, P) f32 -> out_idx_ref (1, k) int32."""
     p = points_ref.shape[-1]
-    pts = points_ref[0]  # (3, P)
+    rows = [points_ref[i : i + 1, :] for i in range(3)]  # x, y, z: (1, P) each
     dmin_ref[...] = jnp.full((1, p), _BIG, jnp.float32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-    def body(t, last):
-        # gather the reference point's coords: dynamic slice on the lane axis
-        ref = jax.lax.dynamic_slice(pts, (0, last), (3, 1))  # (3, 1)
-        diff = pts - ref
-        if metric == "l1":
-            d = jnp.sum(jnp.abs(diff), axis=0, keepdims=True)  # (1, P)
-        else:
-            d = jnp.sum(diff * diff, axis=0, keepdims=True)
-        new_dmin = jnp.minimum(dmin_ref[...], d)
+    def body(t, carry):
+        last, out = carry  # last: int32 scalar, index of the newest sample
+        hit = lane == last
+        diffs = [r - jnp.sum(jnp.where(hit, r, 0.0), axis=1, keepdims=True) for r in rows]
+        new_dmin = jnp.minimum(dmin_ref[...], axis_distance(*diffs, metric))
         dmin_ref[...] = new_dmin
         # in-situ max search (the CAM role): max + first-index-of-max
-        m = jnp.max(new_dmin)
+        m = jnp.max(new_dmin, axis=1, keepdims=True)
         nxt = jnp.min(jnp.where(new_dmin == m, lane, p)).astype(jnp.int32)
-        out_idx_ref[0, t - 1] = last
-        return nxt
+        return nxt, jnp.where(slot == t - 1, last, out)
 
-    last = jax.lax.fori_loop(1, k, body, jnp.int32(0), unroll=False)
-    # the loop wrote indices 0..k-2; write the final sampled index
-    out_idx_ref[0, k - 1] = last
+    # `last` is carried as a scalar: a (1, 1) vector carry needs a relayout
+    # Mosaic does not implement ("Sublane broadcast")
+    init = (jnp.int32(0), jnp.zeros((1, k), jnp.int32))
+    last, out = jax.lax.fori_loop(1, k, body, init)
+    # the loop recorded samples 0..k-2; the final one goes in slot k-1
+    out_idx_ref[...] = jnp.where(slot == k - 1, last, out)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "interpret"))
@@ -78,13 +85,14 @@ def fps_tiles_pallas(
         raise ValueError(f"P={p} must be a multiple of 128 (TPU lane width)")
 
     kernel = functools.partial(_fps_kernel, k=k, metric=metric)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(t,),
-        in_specs=[pl.BlockSpec((1, 3, p), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, k), jnp.int32),
+        in_specs=[pl.BlockSpec((None, 3, p), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1, k), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, p), jnp.float32)],
         interpret=interpret,
         name="pc2im_fps_tile",
     )(points)
+    return out[:, 0, :]

@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.fps import axis_distance
+
 
 def fps_tiles_ref(points: jax.Array, k: int, *, metric: str = "l1") -> jax.Array:
     """points: (T, 3, P) -> (T, k) int32.  Matches the kernel's tie-breaking
@@ -17,10 +19,7 @@ def fps_tiles_ref(points: jax.Array, k: int, *, metric: str = "l1") -> jax.Array
             dmin, last = carry
             ref = jax.lax.dynamic_slice(pts, (0, last), (3, 1))
             diff = pts - ref
-            if metric == "l1":
-                d = jnp.sum(jnp.abs(diff), axis=0)
-            else:
-                d = jnp.sum(diff * diff, axis=0)
+            d = axis_distance(diff[0], diff[1], diff[2], metric)
             new_dmin = jnp.minimum(dmin, d)
             nxt = jnp.argmax(new_dmin).astype(jnp.int32)  # first max index
             return (new_dmin, nxt), last

@@ -2,15 +2,15 @@
 
 The full (M, P) distance matrix never reaches HBM: per centroid block, the
 kernel computes L1 distances into VMEM, thresholds at L = 1.6R, and selects
-the FIRST `nsample` in-range indices (PointNet++ semantics) via a cumsum
-slot-match — all in one pass.  HBM output is just (M, nsample) indices +
-mask, exactly the paper's 'distances are consumed in-situ by the sorter'.
+the FIRST `nsample` in-range indices (PointNet++ semantics) in one pass.
+HBM output is just (M, nsample) indices + mask, exactly the paper's
+'distances are consumed in-situ by the sorter'.
 
-first-k as dense ops (Mosaic-friendly, no scatter):
-    hits   = d <= L                      (bc, P)
-    ranks  = cumsum(hits) along P        (bc, P)  1-based at hit positions
-    slot s taken by the column j with hits[j] and ranks[j] == s+1
-    idx[s] = min over j of (hits & ranks==s+1 ? j : P)   -> (bc, nsample)
+first-k as dense ops (Mosaic-friendly: no scatter, no prefix sum):
+    hits   = d <= L                                   (bc, P)
+    idx[s] = min over j of (hits[j] & j > idx[s-1] ? j : P),  idx[-1] = -1
+slot s is the smallest hit column past slot s-1's, i.e. the (s+1)-th hit;
+once a slot finds nothing (P) every later slot finds nothing too.
 """
 
 from __future__ import annotations
@@ -21,27 +21,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.fps import axis_distance
+
 
 def _lattice_kernel(c_ref, p_ref, idx_ref, mask_ref, *, nsample: int, l_range: float):
     """c_ref (bc, 3), p_ref (3, P) -> idx (bc, nsample) int32, mask bool."""
-    c = c_ref[...]
-    p = p_ref[...]
-    d = jnp.sum(jnp.abs(c[:, :, None] - p[None, :, :]), axis=1)  # (bc, P) L1
+    diffs = [c_ref[:, i : i + 1] - p_ref[i : i + 1, :] for i in range(3)]
+    d = axis_distance(*diffs, "l1")  # (bc, P)
     bc, pp = d.shape
     hits = d <= l_range
-    ranks = jnp.cumsum(hits.astype(jnp.int32), axis=1)  # (bc, P)
     lane = jax.lax.broadcasted_iota(jnp.int32, (bc, pp), 1)
+    prev = jnp.full((bc,), -1, jnp.int32)
+    first = None
     for s in range(nsample):
-        sel = hits & (ranks == (s + 1))
-        j = jnp.min(jnp.where(sel, lane, pp), axis=1)  # (bc,)
-        found = j < pp
-        idx_ref[:, s] = jnp.where(found, j, 0).astype(jnp.int32)
+        prev = jnp.min(jnp.where(hits & (lane > prev[:, None]), lane, pp), axis=1)
+        found = prev < pp
+        if first is None:  # empty slots repeat the first hit (PointNet++)
+            first = jnp.where(found, prev, 0)
+        idx_ref[:, s] = jnp.where(found, prev, first).astype(jnp.int32)
         mask_ref[:, s] = found
-    # pad empty slots with the first hit (PointNet++ convention)
-    first = idx_ref[:, 0]
-    for s in range(1, nsample):
-        m = mask_ref[:, s]
-        idx_ref[:, s] = jnp.where(m, idx_ref[:, s], first)
 
 
 @functools.partial(

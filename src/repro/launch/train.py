@@ -23,6 +23,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs.base import get_config
 from repro.core.policy import ExecutionPolicy, resolve_policy
 from repro.data.tokens import Prefetcher, token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw_init
 from repro.runtime import StragglerMonitor, run_with_restarts
 
@@ -141,6 +142,7 @@ def train_lm(cfg, args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU-friendly)")

@@ -87,15 +87,16 @@ def _linear_tensor_sharded(p, x: jax.Array, policy: ExecutionPolicy) -> jax.Arra
     Each device multiplies against its slice of the weight columns and the
     partial products are concatenated with a tiled all_gather — the paper's
     SC dataflow lifted to a device group.  Bitwise-equal to the replicated
-    linear: fp32 columns are independent; the quantized path quantizes the
-    FULL weight first (global per-tensor scale) and slices the integer
-    planes, whose matmul is exact, so column subsets match the unsharded
-    product exactly.  N is zero-padded up to a multiple of the group size;
+    linear on the TPU: fp32 columns are independent (the CPU backend alone
+    sums a narrow dot in a width-dependent order); the quantized path
+    quantizes the FULL weight first (global per-tensor scale) and slices the
+    integer planes, whose matmul is exact, so column subsets match the
+    unsharded product exactly.  N is zero-padded up to a multiple of the group size;
     the pad columns are dropped after the gather.
     """
     w = p["w"]
     k, n = w.shape
-    group = int(jax.core.axis_frame(REPLICA_AXIS))  # static axis size
+    group = jax.lax.axis_size(REPLICA_AXIS)  # static axis size
     idx = jax.lax.axis_index(REPLICA_AXIS)
     cols = -(-n // group)  # ceil: last shard may hold zero-pad columns
     bits = policy.quant_bits
@@ -147,11 +148,33 @@ def layernorm_init(d: int, dtype=jnp.float32):
     return {"g": jnp.ones((d,), dtype), "b": jnp.zeros((d,), dtype)}
 
 
+def tree_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis as a balanced tree of elementwise adds (keepdims).
+
+    A reduce's association order is the compiler's choice and follows the
+    array's layout, which differs between otherwise equal programs (a TPU
+    lays a narrow matmul output out transposed; a Pallas call's output is
+    row-major).  Elementwise adds over halves fix the order, so the sum has
+    the same bits in every program and on every backend.  That matters where
+    it feeds a rounding step: one ulp in a LayerNorm statistic flips SC
+    activation codes downstream.  Zero padding up to a power of two is exact.
+    """
+    c = x.shape[-1]
+    width = 1 << max(c - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - c)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x
+
+
 def layernorm(p, x: jax.Array, eps: float = 1e-5) -> jax.Array:
-    # stats in f32, normalisation applied in the input dtype (see rmsnorm)
+    # stats in f32 (fixed summation order, see tree_sum), normalisation
+    # applied in the input dtype (see rmsnorm)
     x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
+    c = x.shape[-1]
+    mu = tree_sum(x32) / c
+    var = tree_sum(jnp.square(x32 - mu)) / c
     y = (x - mu.astype(x.dtype)) * jax.lax.rsqrt(var + eps).astype(x.dtype)
     return y * p["g"] + p["b"]
 

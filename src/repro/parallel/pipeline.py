@@ -19,7 +19,6 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -150,11 +149,11 @@ def pipeline_forward(
         return outs
 
     pspec_params = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(pspec_params, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params_stacked, x)

@@ -42,10 +42,10 @@ def replica_axis_active() -> bool:
     policy object is safe to thread through both worlds.
     """
     try:
-        jax.core.axis_frame(REPLICA_AXIS)
-        return True
+        jax.lax.axis_size(REPLICA_AXIS)
     except NameError:
         return False
+    return True
 
 
 @contextlib.contextmanager

@@ -33,9 +33,14 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Runs before the test body in the child: force the device count (before any
 # jax import!), then expose emit().  The payload is flushed by an explicit
 # call appended AFTER the body, so a failing child never ships half a payload.
+# Single-threaded Eigen: multi-threaded CPU matmuls split their sums by
+# thread count and problem size, so a 1-row shard would round differently
+# from the same row inside a full batch.
 _PRELUDE = """\
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=%d --xla_cpu_multi_thread_eigen=false"
+)
 import numpy as np
 _PAYLOAD = {}
 def emit(name, value):
@@ -59,6 +64,9 @@ def run_in_child(
         out = os.path.join(td, "payload.npz")
         script = _PRELUDE % n_devices + textwrap.dedent(body) + "\n_flush_payload()\n"
         env = dict(os.environ)
+        # CPU-only by design: forced host devices exist only on the CPU
+        # backend, and the parent may hold the accelerator
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = "src"
         env["PC2IM_MULTIDEV_OUT"] = out
         res = subprocess.run(

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis import given, settings, st
-from jax.experimental import enable_x64
 
 from repro.core import quant as QT
 
@@ -42,7 +41,7 @@ class TestSCMatmul:
     def test_exact_int64(self, m, k, n):
         x = _randint16((m, k), 0)
         w = _randint16((k, n), 1)
-        with enable_x64():
+        with jax.enable_x64():
             got = np.array(QT.sc_matmul(jnp.array(x), jnp.array(w), combine="int64"))
         ref = x.astype(np.int64) @ w.astype(np.int64)
         np.testing.assert_array_equal(got, ref)
@@ -90,7 +89,7 @@ def test_property_sc_matmul_exact(seed, k):
     key = jax.random.PRNGKey(seed)
     x = jax.random.randint(key, (3, k), -32768, 32768, dtype=jnp.int32)
     w = jax.random.randint(jax.random.PRNGKey(seed + 1), (k, 5), -32768, 32768, dtype=jnp.int32)
-    with enable_x64():
+    with jax.enable_x64():
         got = np.array(QT.sc_matmul(x, w, combine="int64"))
     ref = np.array(x, np.int64) @ np.array(w, np.int64)
     np.testing.assert_array_equal(got, ref)

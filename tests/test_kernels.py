@@ -56,7 +56,10 @@ class TestFPSKernel:
 
 
 class TestSCMatmulKernel:
-    @pytest.mark.parametrize("m,k,n", [(8, 64, 16), (32, 128, 32), (128, 512, 128)])
+    # (192, 259, 256): cls global MLP at max_batch=3 — M padded to 256 rows
+    @pytest.mark.parametrize(
+        "m,k,n", [(8, 64, 16), (32, 128, 32), (128, 512, 128), (192, 259, 256)]
+    )
     def test_exact_vs_f32_oracle(self, m, k, n):
         x = jax.random.randint(jax.random.PRNGKey(0), (m, k), -32768, 32768, jnp.int32)
         w = jax.random.randint(jax.random.PRNGKey(1), (k, n), -32768, 32768, jnp.int32)

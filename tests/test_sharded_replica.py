@@ -1,10 +1,11 @@
 """Mesh-sharded replicas: parity, carving, cache keys, chaos on a group.
 
-The tentpole claim is bitwise: a replica that owns a device GROUP and runs
+The tentpole claim is parity: a replica that owns a device GROUP and runs
 the sharded artifact (batch-sharded preprocess, or tensor-sharded feature
 MLPs with concatenated partials — the paper's split-concatenate dataflow)
-returns exactly the bits the single-device artifact returns, for fp32 AND
-SC-quantized policies.  Host-side tests cover the pure math (group carving,
+returns what the single-device artifact returns, for fp32 AND SC-quantized
+policies — bitwise, except fp32 tensor mode on the CPU backend, which is held
+to 1e-4 x max|logit|.  Host-side tests cover the pure math (group carving,
 policy validation, cache-key isolation, assemble/scatter shard locality);
 the multi-device proofs run in forced-host-device subprocesses via
 tests/_multidev.py, with the parity asserts living HERE in the parent.
@@ -78,6 +79,29 @@ class TestShardingKnob:
             assert p_logits == P(REPLICA_AXIS)
         with pytest.raises(ValueError):
             replica_specs("bogus")
+
+    def test_replica_axis_active_only_inside_replica_mesh(self):
+        """The knob's inertness rests on this probe: the axis is unbound
+        under plain jit and bound inside shard_map over REPLICA_AXIS."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import make_replica_mesh
+        from repro.sharding.hints import replica_axis_active
+
+        seen = []
+
+        def probe(x):
+            seen.append(replica_axis_active())
+            return x
+
+        jax.jit(probe)(jnp.zeros(2))
+        mesh = make_replica_mesh(jax.devices()[:1])
+        jax.jit(
+            jax.shard_map(probe, mesh=mesh, in_specs=P(), out_specs=P())
+        )(jnp.zeros(2))
+        assert seen == [False, True]
 
     def test_cache_key_isolation(self):
         """sharding hashes into the artifact cache exactly like pipeline
@@ -184,11 +208,17 @@ def test_assemble_scatter_row_locality_under_any_split(data):
 
 
 def test_sharded_parity_all_modes_subprocess():
-    """Every (mode x quant x group-size) sharded artifact is bitwise-equal
-    to the single-device artifact of the same quant policy, on the same
-    batch.  batch mode needs the pmax-globalized activation amax; tensor
-    mode needs the full-weight (global-scale) quantization before the
-    integer column slice — this test pins both."""
+    """Every (mode x quant x group-size) sharded artifact matches the
+    single-device artifact of the same quant policy, on the same batch.
+
+    batch mode is bitwise-equal: it needs the pmax-globalized activation
+    amax, and each row's math is otherwise untouched.  SC tensor mode is
+    bitwise-equal too: it needs the full-weight (global-scale) quantization
+    before the integer column slice, and integer column products are exact.
+    fp32 tensor mode is held to 1e-4 x max|logit|: its column blocks are
+    float matmuls of another width, and XLA's CPU backend picks the
+    reduction order of a dot by its width (below 64 columns), so the last
+    bits differ."""
     payload = run_in_child(
         """
         import jax, numpy as np
@@ -238,10 +268,16 @@ def test_sharded_parity_all_modes_subprocess():
         n_devices=8,
     )
     for quant in ("none", "sc_w16a16"):
-        for mode in ("batch", "tensor"):
-            for g in (2, 8):
-                assert_bitwise(payload, f"out_{quant}_{mode}_{g}", f"ref_{quant}")
-    assert_bitwise(payload, "seg_out", "seg_ref")
+        ref = payload[f"ref_{quant}"]
+        for g in (2, 8):
+            assert_bitwise(payload, f"out_{quant}_batch_{g}", f"ref_{quant}")
+            if quant == "none":
+                err = np.abs(payload[f"out_{quant}_tensor_{g}"] - ref).max()
+                assert err <= 1e-4 * np.abs(ref).max(), (quant, g, err)
+            else:
+                assert_bitwise(payload, f"out_{quant}_tensor_{g}", f"ref_{quant}")
+    seg_err = np.abs(payload["seg_out"] - payload["seg_ref"]).max()
+    assert seg_err <= 1e-4 * np.abs(payload["seg_ref"]).max(), seg_err
 
 
 # -- ReplicaPool over device groups: carving, warmup, chaos, warm rejoin ------
