@@ -695,14 +695,19 @@ def run_slo(smoke: bool = False, seed: int = 0) -> list[dict]:
 # 2-device mesh replicas in both sharding modes, self-asserting every
 # response is bitwise-equal to the single-device reference before reporting
 # any number (fp32 forward is batch-size independent bitwise, so B=1
-# references are exact).  Rows come back as JSON via PC2IM_SHARD_OUT.
+# references are exact).  The CPU backend sums a dot in an order that
+# depends on its shape, so tensor mode's reference multiplies every linear in
+# the same column blocks its devices do, on a micro-batch of the runtime's
+# static shape (max_batch rows, filler rows zero).  Rows come back as JSON
+# via PC2IM_SHARD_OUT.
 _SHARD_CHILD = """\
 import json, os, time
 
-import jax, numpy as np
+import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
-from repro.core.accelerator import get_accelerator
+from repro.core.accelerator import PC2IMAccelerator, get_accelerator
 from repro.core.policy import ExecutionPolicy
+from repro.models import nn
 from repro.serve import RuntimeConfig, ServingRuntime
 
 smoke = bool(int(os.environ["PC2IM_SHARD_SMOKE"]))
@@ -720,10 +725,36 @@ clouds = [
 ]
 refs = [np.asarray(base.infer(params, c[None]))[0] for c in clouds]
 
+
+def column_blocked_refs(group, max_batch):
+    def blocked(p, x, policy=None):
+        w = p["w"]
+        n = w.shape[1]
+        cols = -(-n // group)
+        wp = jnp.pad(w, ((0, 0), (0, cols * group - n)))
+        y = jnp.concatenate(
+            [x @ wp[:, i * cols:(i + 1) * cols] for i in range(group)], axis=-1
+        )[..., :n]
+        return y + p["b"] if "b" in p else y
+
+    plain, nn.linear = nn.linear, blocked
+    try:
+        accel = PC2IMAccelerator(cfg)
+        out = []
+        for c in clouds:
+            batch = np.zeros((max_batch,) + c.shape, np.float32)
+            batch[0] = c
+            out.append(np.asarray(accel.infer(params, batch))[0])
+        return out
+    finally:
+        nn.linear = plain
+
+
 rows = []
 for mode in (None, "batch", "tensor"):
     pol = ExecutionPolicy(sharding=mode)
     per = 1 if mode is None else 2
+    mode_refs = column_blocked_refs(per, max_batch=4) if mode == "tensor" else refs
     rt = ServingRuntime(
         cfg,
         params,
@@ -741,15 +772,10 @@ for mode in (None, "batch", "tensor"):
             outs.append(f.result(timeout=600))
             lats.append(time.perf_counter() - t_sub)
     wall = time.perf_counter() - t0
-    for o, r in zip(outs, refs):
-        # fp32 tensor mode's column blocks are dots of another width, which
-        # the CPU backend sums in another order (see
-        # tests/test_sharded_replica.py); the other modes are bitwise
-        if mode == "tensor":
-            ok = np.abs(o - r).max() <= 1e-4 * np.abs(r).max()
-        else:
-            ok = np.array_equal(o, r)
-        assert ok, f"serve_shard: sharding={mode} response != single-device"
+    for o, r in zip(outs, mode_refs):
+        assert np.array_equal(o, r), (
+            f"serve_shard: sharding={mode} response != single-device bits"
+        )
     n_rep = len(rt.pool.replicas)
     tag = mode or "unsharded"
     rows.append({
@@ -757,7 +783,7 @@ for mode in (None, "batch", "tensor"):
         "us": float(np.percentile(lats, 95)) * 1e6,
         "note": (
             f"{len(outs) / wall:.1f} req/s over {n_rep}x{per}-device replicas"
-            f" (forced host devices); parity ok"
+            f" (forced host devices); parity bitwise-ok"
         ),
     })
 
@@ -771,10 +797,9 @@ def run_shard(smoke: bool = False, seed: int = 0) -> list[dict]:
 
     Runs in a subprocess with ``xla_force_host_platform_device_count=4``
     (the parent process must keep its single-device view) and SELF-ASSERTS
-    parity of every sharded response against the single-device reference
-    (bitwise, and within 1e-4 x max|logit| for tensor mode) before any
-    throughput number is reported — a parity break fails the lane, not just
-    a dashboard.
+    bitwise parity of every sharded response against the single-device
+    reference before any throughput number is reported — a parity break
+    fails the lane, not just a dashboard.
 
     Forced host devices timeshare one CPU, so the throughput columns here
     measure dispatch/overhead plumbing, not real multi-chip scaling.
@@ -792,7 +817,8 @@ def run_shard(smoke: bool = False, seed: int = 0) -> list[dict]:
         env = dict(os.environ)
         # CPU-only by design: forced host devices exist only on the CPU
         # backend, and this parent may hold the accelerator.  Single-threaded
-        # Eigen keeps a 1-row shard's matmul sums in full-batch order.
+        # Eigen keeps a row's matmul bits independent of the batch size (see
+        # tests/_multidev.py).
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             "--xla_force_host_platform_device_count=4"

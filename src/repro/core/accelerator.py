@@ -330,9 +330,10 @@ class MeshArtifacts:
         replicated logits.
 
     Both modes are bitwise-equal to the accelerator's single-device
-    `infer` on the same batch on the TPU (`chip_smoke.py --chips 4`); on
-    the CPU backend fp32 tensor mode is held to 1e-4 x max|logit| instead
-    (tests/test_sharded_replica.py).
+    `infer` on the same batch (pinned by tests/test_sharded_replica.py;
+    on the CPU backend, whose dot bits depend on the dot's width, fp32
+    tensor mode is compared with a forward that multiplies in the same
+    column blocks; on the chip by `chip_smoke.py --chips 4`).
     `check_vma=False` matches the repo's shard_map precedent
     (parallel/pipeline.py) — the tensor mode's gathered intermediates are
     replicated values the varying-axes checker can't see through.

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.fps import axis_distance
 from repro.kernels import registry
 
 _INF = 3.0e38  # python float: jnp scalars would be captured consts in the kernel
@@ -26,13 +27,8 @@ _INF = 3.0e38  # python float: jnp scalars would be captured consts in the kerne
 
 def _knn3_kernel(q_ref, p_ref, idx_ref, dist_ref, *, metric: str, k: int):
     """q_ref (bq, 3), p_ref (3, P) -> idx_ref (bq, k) int32, dist_ref (bq, k) f32."""
-    q = q_ref[...]  # (bq, 3)
-    p = p_ref[...]  # (3, P)
-    diff = q[:, :, None] - p[None, :, :]  # (bq, 3, P)
-    if metric == "l1":
-        d = jnp.sum(jnp.abs(diff), axis=1)  # (bq, P)
-    else:
-        d = jnp.sum(diff * diff, axis=1)
+    diffs = [q_ref[:, i : i + 1] - p_ref[i : i + 1, :] for i in range(3)]
+    d = axis_distance(*diffs, metric)  # (bq, P)
     bq, pp = d.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (bq, pp), 1)
     for t in range(k):

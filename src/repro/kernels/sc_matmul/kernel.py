@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import registry
+
 PLANE_BITS = 4
 
 
@@ -111,9 +113,13 @@ def sc_matmul_pallas(
     m, k = x_q.shape
     k2, n = w_q.shape
     assert k == k2
-    if m % bm or n % bn or k % bk:
-        bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-        if m % bm or n % bn or k % bk:
+    # M <= bm is one whole block; a longer M is padded with copies of row 0
+    # up to a whole number of blocks, and the pad rows are sliced off
+    bm = min(bm, m)
+    x_q, _ = registry.pad_to_multiple(x_q, axis=0, multiple=bm)
+    if n % bn or k % bk:
+        bn, bk = min(bn, n), min(bk, k)
+        if n % bn or k % bk:
             raise ValueError(f"shapes ({m},{k},{n}) not tileable by ({bm},{bn},{bk})")
     k_steps = k // bk
     n_diags = n_planes_x + n_planes_w - 1
@@ -126,14 +132,14 @@ def sc_matmul_pallas(
     )
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, k_steps),
+        grid=(x_q.shape[0] // bm, n // bn, k_steps),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((x_q.shape[0], n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32) for _ in range(n_diags)],
         interpret=interpret,
         name="pc2im_sc_matmul",
-    )(x_q, w_q)
+    )(x_q, w_q)[:m]

@@ -34,17 +34,10 @@ def sc_matmul_op(
     backend: str = "auto",
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Exact integer matmul via SC planes.  (M,K) x (K,N) int32 -> (M,N) f32.
-
-    The Pallas kernel tiles M by 128 rows (or takes M <= 128 whole), so any
-    other M is padded up to the block multiple and the pad rows sliced off.
-    """
+    """Exact integer matmul via SC planes.  (M,K) x (K,N) int32 -> (M,N) f32."""
     n_planes = bits // 4
-    resolved, impl = registry.dispatch("sc_matmul", backend, interpret)
-    m = x_q.shape[0]
-    if resolved == "pallas" and m > registry.LANE:
-        x_q, _ = registry.pad_to_multiple(x_q, axis=0, multiple=registry.LANE)
-    return impl(x_q, w_q, n_planes=n_planes)[:m]
+    _, impl = registry.dispatch("sc_matmul", backend, interpret)
+    return impl(x_q, w_q, n_planes=n_planes)
 
 
 def sc_quantized_linear(

@@ -87,12 +87,13 @@ def _linear_tensor_sharded(p, x: jax.Array, policy: ExecutionPolicy) -> jax.Arra
     Each device multiplies against its slice of the weight columns and the
     partial products are concatenated with a tiled all_gather — the paper's
     SC dataflow lifted to a device group.  Bitwise-equal to the replicated
-    linear on the TPU: fp32 columns are independent (the CPU backend alone
-    sums a narrow dot in a width-dependent order); the quantized path
-    quantizes the FULL weight first (global per-tensor scale) and slices the
-    integer planes, whose matmul is exact, so column subsets match the
-    unsharded product exactly.  N is zero-padded up to a multiple of the group size;
-    the pad columns are dropped after the gather.
+    linear: fp32 columns are independent (on a backend whose dot gives a
+    column the same bits at any width; the CPU's does not, see
+    tests/test_sharded_replica.py); the quantized path quantizes the FULL weight first
+    (global per-tensor scale) and slices the integer planes, whose matmul is
+    exact, so column subsets match the unsharded product exactly.  N is
+    zero-padded up to a multiple of the group size; the pad columns are
+    dropped after the gather.
     """
     w = p["w"]
     k, n = w.shape
@@ -151,13 +152,12 @@ def layernorm_init(d: int, dtype=jnp.float32):
 def tree_sum(x: jax.Array) -> jax.Array:
     """Sum over the last axis as a balanced tree of elementwise adds (keepdims).
 
-    A reduce's association order is the compiler's choice and follows the
-    array's layout, which differs between otherwise equal programs (a TPU
-    lays a narrow matmul output out transposed; a Pallas call's output is
-    row-major).  Elementwise adds over halves fix the order, so the sum has
-    the same bits in every program and on every backend.  That matters where
-    it feeds a rounding step: one ulp in a LayerNorm statistic flips SC
-    activation codes downstream.  Zero padding up to a power of two is exact.
+    A reduce's association order is the compiler's choice and may differ
+    between otherwise equal programs.  Elementwise adds over halves fix the
+    order, so the sum has the same bits in every program and on every
+    backend.  That matters where it feeds a rounding step: one ulp in a
+    LayerNorm statistic can flip SC activation codes downstream.  Zero
+    padding up to a power of two is exact.
     """
     c = x.shape[-1]
     width = 1 << max(c - 1, 0).bit_length()
@@ -168,13 +168,18 @@ def tree_sum(x: jax.Array) -> jax.Array:
     return x
 
 
-def layernorm(p, x: jax.Array, eps: float = 1e-5) -> jax.Array:
-    # stats in f32 (fixed summation order, see tree_sum), normalisation
-    # applied in the input dtype (see rmsnorm)
+def layernorm(p, x: jax.Array, eps: float = 1e-5, *, fixed_order: bool = False) -> jax.Array:
+    # stats in f32, normalisation applied in the input dtype (see rmsnorm).
+    # fixed_order: sum the stats with tree_sum, for a LayerNorm whose output
+    # is quantized next (mlp_apply under an SC policy)
     x32 = x.astype(jnp.float32)
-    c = x.shape[-1]
-    mu = tree_sum(x32) / c
-    var = tree_sum(jnp.square(x32 - mu)) / c
+    if fixed_order:
+        c = x.shape[-1]
+        mu = tree_sum(x32) / c
+        var = tree_sum(jnp.square(x32 - mu)) / c
+    else:
+        mu = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.var(x32, axis=-1, keepdims=True)
     y = (x - mu.astype(x.dtype)) * jax.lax.rsqrt(var + eps).astype(x.dtype)
     return y * p["g"] + p["b"]
 
@@ -196,10 +201,12 @@ def mlp_apply(
     p, x: jax.Array, *, final_act: bool = True, policy: ExecutionPolicy | None = None
 ) -> jax.Array:
     n = len(p["layers"])
+    # under an SC policy every LayerNorm output reaches a quantized linear
+    quantized = policy is not None and policy.quant_bits is not None
     for i, lay in enumerate(p["layers"]):
         x = linear(lay["lin"], x, policy=policy)
         if "ln" in lay:
-            x = layernorm(lay["ln"], x)
+            x = layernorm(lay["ln"], x, fixed_order=quantized)
         if final_act or i < n - 1:
             x = jax.nn.relu(x)
     return x

@@ -33,9 +33,9 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Runs before the test body in the child: force the device count (before any
 # jax import!), then expose emit().  The payload is flushed by an explicit
 # call appended AFTER the body, so a failing child never ships half a payload.
-# Single-threaded Eigen: multi-threaded CPU matmuls split their sums by
-# thread count and problem size, so a 1-row shard would round differently
-# from the same row inside a full batch.
+# Single-threaded Eigen: with multi-threaded CPU matmuls a row's bits depend
+# on how many rows share the dot, and B=1 references and 1-row batch shards
+# need them not to (pinned in tests/test_sharded_replica.py).
 _PRELUDE = """\
 import os
 os.environ["XLA_FLAGS"] = (

@@ -135,6 +135,31 @@ class TestQuantizedLinearParity:
         )
 
 
+class TestLayerNormOrder:
+    @pytest.mark.parametrize("width", [1, 3, 64, 67, 768])
+    def test_tree_sum_is_exact_sum(self, width):
+        # small integers: every association order gives the exact sum
+        x = jax.random.randint(jax.random.PRNGKey(0), (5, width), -100, 100)
+        x = x.astype(np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(nn.tree_sum(x)), np.asarray(x).sum(-1, keepdims=True)
+        )
+
+    def test_fixed_order_only_under_a_quantizing_policy(self):
+        """An SC policy's LayerNorms sum with tree_sum (no reduce the
+        compiler may reassociate ahead of a quantization); the float path
+        keeps the plain reduce."""
+        p = nn.mlp_init(jax.random.PRNGKey(0), [3, 64, 67])
+        x = jax.numpy.ones((2, 16, 3))
+        for policy, reduces in (
+            (None, True),
+            (ExecutionPolicy(quant="sc_w16a16", backend="xla"), False),
+            (ExecutionPolicy(quant="sc_w8a8", backend="xla"), False),
+        ):
+            jaxpr = jax.make_jaxpr(lambda p, x: nn.mlp_apply(p, x, policy=policy))(p, x)
+            assert ("reduce_sum" in str(jaxpr)) is reduces, policy
+
+
 def _smoke_setup(quant="none", batch=2):
     cfg = get_config("pointnet2-cls", smoke=True)
     policy = ExecutionPolicy(quant=quant, backend="xla")

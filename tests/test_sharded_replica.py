@@ -1,11 +1,12 @@
 """Mesh-sharded replicas: parity, carving, cache keys, chaos on a group.
 
-The tentpole claim is parity: a replica that owns a device GROUP and runs
+The tentpole claim is bitwise: a replica that owns a device GROUP and runs
 the sharded artifact (batch-sharded preprocess, or tensor-sharded feature
 MLPs with concatenated partials — the paper's split-concatenate dataflow)
-returns what the single-device artifact returns, for fp32 AND SC-quantized
-policies — bitwise, except fp32 tensor mode on the CPU backend, which is held
-to 1e-4 x max|logit|.  Host-side tests cover the pure math (group carving,
+returns exactly the bits the single-device artifact returns, for fp32 AND
+SC-quantized policies.  On the CPU backend an fp32 dot's bits depend on its
+width, so fp32 tensor mode is compared with a single-device forward whose
+linears multiply in the same column blocks.  Host-side tests cover the pure math (group carving,
 policy validation, cache-key isolation, assemble/scatter shard locality);
 the multi-device proofs run in forced-host-device subprocesses via
 tests/_multidev.py, with the parity asserts living HERE in the parent.
@@ -208,23 +209,56 @@ def test_assemble_scatter_row_locality_under_any_split(data):
 
 
 def test_sharded_parity_all_modes_subprocess():
-    """Every (mode x quant x group-size) sharded artifact matches the
-    single-device artifact of the same quant policy, on the same batch.
+    """Every (mode x quant x group-size) sharded artifact is bitwise-equal
+    to the single-device artifact of the same quant policy, on the same
+    batch.  batch mode needs the pmax-globalized activation amax; tensor
+    mode needs the full-weight (global-scale) quantization before the
+    integer column slice — this test pins both.
 
-    batch mode is bitwise-equal: it needs the pmax-globalized activation
-    amax, and each row's math is otherwise untouched.  SC tensor mode is
-    bitwise-equal too: it needs the full-weight (global-scale) quantization
-    before the integer column slice, and integer column products are exact.
-    fp32 tensor mode is held to 1e-4 x max|logit|: its column blocks are
-    float matmuls of another width, and XLA's CPU backend picks the
-    reduction order of a dot by its width (below 64 columns), so the last
-    bits differ."""
+    fp32 tensor mode multiplies each device's column block as a dot of
+    that block's width, and the CPU backend picks a dot's summation order
+    by its shape: a narrow dot is not bitwise the same columns of a wide
+    one (pinned below, with the row independence the B=1 references of
+    the serving tests rely on).  Its reference is therefore the
+    single-device forward with every fp32 linear multiplied in the same
+    column blocks; everything else in it is the single-device program."""
     payload = run_in_child(
         """
-        import jax, numpy as np
+        import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import get_config
-        from repro.core.accelerator import get_accelerator
+        from repro.core.accelerator import PC2IMAccelerator, get_accelerator
         from repro.core.policy import ExecutionPolicy
+        from repro.models import nn
+
+        def column_blocked_infer(cfg, params, pts, group):
+            # single-device fp32 forward; each linear multiplies in the
+            # column blocks a tensor-sharded group of `group` devices uses
+            def blocked(p, x, policy=None):
+                w = p["w"]
+                n = w.shape[1]
+                cols = -(-n // group)
+                wp = jnp.pad(w, ((0, 0), (0, cols * group - n)))
+                y = jnp.concatenate(
+                    [x @ wp[:, i * cols:(i + 1) * cols] for i in range(group)],
+                    axis=-1,
+                )[..., :n]
+                return y + p["b"] if "b" in p else y
+
+            plain, nn.linear = nn.linear, blocked
+            try:
+                return PC2IMAccelerator(cfg).infer(params, pts)
+            finally:
+                nn.linear = plain
+
+        # the CPU dot's shape dependence, in isolation
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
+        matmul = jax.jit(lambda a, b: a @ b)
+        emit("dot_wide_cols", matmul(x, w)[:, :16])
+        emit("dot_narrow", matmul(x, w[:, :16]))
+        emit("dot_rows", matmul(x, w)[:1])
+        emit("dot_one_row", matmul(x[:1], w))
 
         cfg = get_config("pointnet2-cls", smoke=True)
         base = get_accelerator(cfg)
@@ -247,6 +281,8 @@ def test_sharded_parity_all_modes_subprocess():
                 for g in (2, 8):
                     arts = accel.mesh_artifacts(jax.devices()[:g])
                     emit(f"out_{quant}_{mode}_{g}", arts.infer(params, pts))
+        for g in (2, 8):
+            emit(f"ref_none_tensor_{g}", column_blocked_infer(cfg, params, pts, g))
 
         # seg head through tensor sharding: per-point logits concatenate the
         # same way, and the out-spec row slice round-trips (4 rows / 4 shards)
@@ -259,7 +295,7 @@ def test_sharded_parity_all_modes_subprocess():
             ),
             np.float32,
         )
-        emit("seg_ref", sbase.infer(sparams, spts))
+        emit("seg_ref", column_blocked_infer(seg, sparams, spts, 4))
         sarts = get_accelerator(
             seg, ExecutionPolicy(sharding="tensor")
         ).mesh_artifacts(jax.devices()[:4])
@@ -267,17 +303,14 @@ def test_sharded_parity_all_modes_subprocess():
         """,
         n_devices=8,
     )
-    for quant in ("none", "sc_w16a16"):
-        ref = payload[f"ref_{quant}"]
-        for g in (2, 8):
+    assert not np.array_equal(payload["dot_narrow"], payload["dot_wide_cols"])
+    assert_bitwise(payload, "dot_one_row", "dot_rows")
+    for g in (2, 8):
+        for quant in ("none", "sc_w16a16"):
             assert_bitwise(payload, f"out_{quant}_batch_{g}", f"ref_{quant}")
-            if quant == "none":
-                err = np.abs(payload[f"out_{quant}_tensor_{g}"] - ref).max()
-                assert err <= 1e-4 * np.abs(ref).max(), (quant, g, err)
-            else:
-                assert_bitwise(payload, f"out_{quant}_tensor_{g}", f"ref_{quant}")
-    seg_err = np.abs(payload["seg_out"] - payload["seg_ref"]).max()
-    assert seg_err <= 1e-4 * np.abs(payload["seg_ref"]).max(), seg_err
+        assert_bitwise(payload, f"out_sc_w16a16_tensor_{g}", "ref_sc_w16a16")
+        assert_bitwise(payload, f"out_none_tensor_{g}", f"ref_none_tensor_{g}")
+    assert_bitwise(payload, "seg_out", "seg_ref")
 
 
 # -- ReplicaPool over device groups: carving, warmup, chaos, warm rejoin ------

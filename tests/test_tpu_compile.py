@@ -8,7 +8,8 @@ described, not attached, at the shapes the cls and seg configs run at B=8:
 
   * every SA stage's PreprocessEngine (MSP partition, FPS, lattice query
     and their lane padding);
-  * sc_matmul at every feature layer's (M, K, N) of cls, W16A16 and W8A8.
+  * sc_matmul at every feature layer's (M, K, N) of cls, W16A16 and W8A8;
+  * knn3 at every seg feature-propagation stage's (fine, coarse) sizes.
 
 Each compiled program must call a Mosaic kernel (`tpu_custom_call`).  The
 topology is described inside a fixture — never at import — because only one
@@ -23,6 +24,7 @@ import pytest
 
 from repro.configs import pointnet2_cls, pointnet2_seg
 from repro.core.policy import ExecutionPolicy
+from repro.kernels.knn3.ops import knn3
 from repro.kernels.sc_matmul.ops import sc_matmul_op
 from repro.models import pointnet2 as PN
 
@@ -104,3 +106,19 @@ def test_sc_matmul_compiles(one_chip, m, k, n, bits):
     w = jax.ShapeDtypeStruct((k, n), jnp.int32, sharding=one_chip)
     fn = lambda a, b: sc_matmul_op(a, b, bits=bits, backend="pallas", interpret=False)  # noqa: E731
     assert "tpu_custom_call" in _compiled_text(fn, x, w)
+
+
+def _fp_cases():
+    """(fine, coarse) point counts of each seg FP stage: level i+1 -> level i."""
+    cfg = CONFIGS["seg"]
+    sizes = [cfg.n_points] + [sa.n_centroids for sa in cfg.sa]
+    for i in range(len(cfg.sa)):
+        yield pytest.param(sizes[i], sizes[i + 1], id=f"seg-fp{i}")
+
+
+@pytest.mark.parametrize("n_fine,n_coarse", list(_fp_cases()))
+def test_knn3_compiles(one_chip, n_fine, n_coarse):
+    q = jax.ShapeDtypeStruct((BATCH, n_fine, 3), jnp.float32, sharding=one_chip)
+    p = jax.ShapeDtypeStruct((BATCH, n_coarse, 3), jnp.float32, sharding=one_chip)
+    fn = jax.vmap(lambda a, b: knn3(a, b, backend="pallas", interpret=False))
+    assert "tpu_custom_call" in _compiled_text(fn, q, p)
