@@ -1,0 +1,24 @@
+"""Least time of the traced `pc2im_lattice` calls over their device time (%).
+
+Layer kernels.lattice. The least time of a call is the larger of its operations
+over the compute peak and its bytes over HBM bandwidth (`benchlib.work`);
+the bound is printed on standard error. Moves `clouds_per_s`.
+"""
+
+import sys
+
+from benchlib import work, xtrace
+
+KERNEL = "pc2im_lattice"
+
+
+def read(ctx):
+    """The metric from a traced run's context, or None where nothing was traced."""
+    if ctx.trace is None:
+        return None
+    events = [e.dur * 1e-9 for e in xtrace.kernel_events(ctx.trace, KERNEL)]
+    share = work.roofline_pct(work.lattice_calls(ctx.model, ctx.batch), events, ctx.peaks)
+    if share is None:
+        return None
+    print(f"lattice_roofline: bound {share[1]}, {len(events)} {KERNEL} events", file=sys.stderr)
+    return share[0]
