@@ -176,18 +176,6 @@ def serve_shard_bench(smoke: bool = False) -> list[dict]:
     return serve_load.run_shard(smoke=smoke)
 
 
-def obs_overhead_bench(smoke: bool = False) -> list[dict]:
-    """Tracing-on vs tracing-off throughput on the serve_load open-loop trace
-    (see benchmarks/obs_overhead.py).  ASSERTS tracing-on keeps >= 97% of
-    tracing-off throughput, every span is well-formed (exactly one terminal,
-    monotonic), the stage breakdown sums to the measured e2e latency, and
-    the Chrome-trace JSON export round-trips — failures raise and fail the
-    lane."""
-    from benchmarks import obs_overhead
-
-    return obs_overhead.run(smoke=smoke)
-
-
 def serve_adapt_bench(smoke: bool = False) -> list[dict]:
     """Adaptive control plane: feedback-tuned knobs vs static defaults on a
     shifted size-distribution trace offered above the static capacity (see
@@ -231,24 +219,21 @@ def main() -> None:
         # parity vs the uncached path), the pipelined-overlap lane, the SLO
         # control-plane lane (two-class overload trace with a mid-run replica
         # kill, asserting shed isolation, the interactive p95 budget and warm
-        # rejoin recovery) + the observability-overhead lane (tracing-on vs
-        # tracing-off, asserting the <= 3% throughput budget and span/export
-        # well-formedness) + the sharded mesh-replica lane (forced-host-device
+        # rejoin recovery) + the sharded mesh-replica lane (forced-host-device
         # subprocess asserting parity of sharded vs single-device
         # responses) + the adaptive control-plane lane (feedback-tuned knobs
         # vs static defaults, asserting convergence with logged evidence,
         # bitwise parity across the live reconfiguration, the adapted-beats-
         # static contract and the DRR weight-share floor), reduced size —
         # keeps the open-loop path, the cache hot path, the stage-overlap
-        # speedup, the control plane, the tracing layer, the sharded dispatch
-        # path and the adaptation loop exercised on every push without the
+        # speedup, the control plane, the sharded dispatch path and the
+        # adaptation loop exercised on every push without the
         # full paper-table sweep.
         _print_rows(serve_bench(smoke=True))
         _print_rows(serve_cache_bench(smoke=True))
         _print_rows(pipeline_bench(smoke=True))
         _print_rows(serve_slo_bench(smoke=True))
         _print_rows(serve_shard_bench(smoke=True))
-        _print_rows(obs_overhead_bench(smoke=True))
         _print_rows(serve_adapt_bench(smoke=True))
         return
     for mod_name, kwargs in [
@@ -273,7 +258,6 @@ def main() -> None:
     _print_rows(pipeline_bench())
     _print_rows(serve_slo_bench())
     _print_rows(serve_shard_bench())
-    _print_rows(obs_overhead_bench())
     _print_rows(serve_adapt_bench())
 
 

@@ -1,7 +1,7 @@
-"""Request-lifecycle tracing demo: trace a serve run, export for Perfetto.
+"""Request-lifecycle tracing demo: trace a serve run, profile it for Perfetto.
 
     PYTHONPATH=src python examples/serve_trace.py
-    PYTHONPATH=src python examples/serve_trace.py --requests 96 --out my.json
+    PYTHONPATH=src python examples/serve_trace.py --requests 96 --out my_profile
 
 Runs a traced `ServingRuntime` (TraceConfig attached, periodic Reporter
 printing one metrics line per interval) over a small open-loop trace of
@@ -12,13 +12,17 @@ mixed-size clouds, then shows every consumer of the trace stream:
     execute stage, cross-checked so the stages sum to measured e2e;
   * the batch cross-check (`batch_crosscheck`) tying batch-span durations
     back to the `BatchRecord` totals the metrics layer recorded;
-  * a Chrome-trace JSON written via `write_chrome_trace` — open it at
-    https://ui.perfetto.dev (or chrome://tracing) to see request spans,
-    batch stage slices and control-plane instants on a shared timeline;
+  * a JAX profiler trace of the served window (`jax.profiler.trace` with
+    `create_perfetto_trace=True`) — open its `perfetto_trace.json.gz` at
+    https://ui.perfetto.dev to see the serving stage spans (`batch.h2d`,
+    `batch.execute`, `batch.complete`, ...) on the host threads beside the
+    device ops, whose names carry the model's stage scopes;
   * the Prometheus text exposition of the final metrics snapshot.
 """
 
 import argparse
+import glob
+import os
 import time
 
 import jax
@@ -35,7 +39,6 @@ from repro.serve import (
     request_timelines,
     stage_breakdown,
     trace_problems,
-    write_chrome_trace,
 )
 
 
@@ -44,8 +47,8 @@ def main():
     ap.add_argument("--requests", type=int, default=48)
     ap.add_argument("--rate", type=float, default=150.0,
                     help="open-loop arrival rate, requests/s")
-    ap.add_argument("--out", default="pc2im_trace.json",
-                    help="Chrome-trace JSON output path (load in Perfetto)")
+    ap.add_argument("--out", default="pc2im_profile",
+                    help="profiler output directory (holds the Perfetto trace)")
     args = ap.parse_args()
 
     cfg = get_config("pointnet2-cls", smoke=True)  # n_points=256, CPU-friendly
@@ -67,7 +70,7 @@ def main():
     arrivals = np.cumsum(rng.exponential(1.0 / args.rate, size=args.requests))
     futs = []
     t0 = time.perf_counter()
-    with rt:
+    with jax.profiler.trace(args.out, create_perfetto_trace=True), rt:
         for i in range(args.requests):
             time.sleep(max(0.0, t0 + arrivals[i] - time.perf_counter()))
             futs.append(rt.submit(clouds[i % len(clouds)]))
@@ -93,9 +96,10 @@ def main():
         print(f"\nbatch span vs BatchRecord cross-check: {len(checks)} batches,"
               f" worst rel_err {worst.rel_err:.1%} (batch {worst.batch_id})")
 
-    n = write_chrome_trace(args.out, events)
-    print(f"\nwrote {n} Chrome-trace events to {args.out} — "
-          f"load it at https://ui.perfetto.dev")
+    found = glob.glob(os.path.join(args.out, "plugins", "profile", "*",
+                                   "perfetto_trace.json.gz"))
+    print(f"\nprofiler trace: {max(found, key=os.path.getmtime) if found else 'none'}"
+          f" — load it at https://ui.perfetto.dev")
 
     print("\nPrometheus exposition of the final snapshot:")
     for line in prometheus_text(rt.metrics.snapshot()).splitlines():

@@ -127,6 +127,16 @@ class PC2IMAccelerator:
         """
         return self._forward(params, points)
 
+    @property
+    def infer_program(self):
+        """The jitted artifact `infer` and `forward` run.
+
+        Its `.lower(params, points).compile()` is the program that serves,
+        with the instruction names a profiler trace shows; a `jax.jit` of
+        `infer` would compile another module, under other names.
+        """
+        return self._forward
+
     def loss(self, params, points: jax.Array, labels: jax.Array):
         """jit-compiled (loss, metrics) under this accelerator's policy."""
         return self._loss(params, points, labels)

@@ -172,14 +172,16 @@ class PreprocessEngine:
         """Global FPS + global ball query; the B clouds ARE the kernel tiles."""
         cfg = self.config
         b = points.shape[0]
-        cidx = fps_tiles(
-            points, cfg.n_centroids, metric=cfg.resolved_metric,
-            backend=cfg.backend, interpret=cfg.interpret,
-        )  # (B, M)
-        cxyz = jnp.take_along_axis(points, cidx[..., None], axis=1)  # (B, M, 3)
-        nbrs = jax.vmap(
-            lambda p, c: query_mod.ball_query(p, c, cfg.radius, cfg.nsample)
-        )(points, cxyz)
+        with jax.named_scope("fps"):
+            cidx = fps_tiles(
+                points, cfg.n_centroids, metric=cfg.resolved_metric,
+                backend=cfg.backend, interpret=cfg.interpret,
+            )  # (B, M)
+            cxyz = jnp.take_along_axis(points, cidx[..., None], axis=1)  # (B, M, 3)
+        with jax.named_scope("query"):
+            nbrs = jax.vmap(
+                lambda p, c: query_mod.ball_query(p, c, cfg.radius, cfg.nsample)
+            )(points, cxyz)
         return PreprocessResult(
             cidx, cxyz, nbrs, jnp.ones((b, cfg.n_centroids), bool)
         )
@@ -209,37 +211,41 @@ class PreprocessEngine:
         p = n // t
         k = cfg.n_centroids // t
 
-        # per-cloud MSP (batched argsorts); tiles (B, T, P) global-per-cloud
-        tiles = jax.vmap(
-            lambda pts: part_mod.median_partition(
-                pts, cfg.depth, axis_mode=cfg.axis_mode
-            ).tiles
-        )(points)
+        with jax.named_scope("partition"):
+            # per-cloud MSP (batched argsorts); tiles (B, T, P) global-per-cloud
+            tiles = jax.vmap(
+                lambda pts: part_mod.median_partition(
+                    pts, cfg.depth, axis_mode=cfg.axis_mode
+                ).tiles
+            )(points)
 
-        # FOLD: (B, T, P, 3) -> (B·T, P, 3); one kernel grid for all clouds
-        coords = jnp.take_along_axis(points[:, None], tiles[..., None], axis=2)
-        flat_tiles = tiles.reshape(b * t, p)
-        flat_coords = coords.reshape(b * t, p, 3)
+            # FOLD: (B, T, P, 3) -> (B·T, P, 3); one kernel grid for all clouds
+            coords = jnp.take_along_axis(points[:, None], tiles[..., None], axis=2)
+            flat_tiles = tiles.reshape(b * t, p)
+            flat_coords = coords.reshape(b * t, p, 3)
 
-        local_c = fps_tiles(
-            flat_coords, k, metric=cfg.resolved_metric,
-            backend=cfg.backend, interpret=cfg.interpret,
-        )  # (B·T, k) local
-        cidx = jnp.take_along_axis(flat_tiles, local_c, axis=1)  # global
-        cxyz = jnp.take_along_axis(flat_coords, local_c[..., None], axis=1)
-
-        if cfg.resolved_query == "lattice":
-            nbrs_local = lattice_query_tiles(
-                flat_coords, cxyz, cfg.radius, cfg.nsample,
+        with jax.named_scope("fps"):
+            local_c = fps_tiles(
+                flat_coords, k, metric=cfg.resolved_metric,
                 backend=cfg.backend, interpret=cfg.interpret,
-            )
-        else:  # per-tile ball query: no kernel counterpart, XLA path
-            nbrs_local = jax.vmap(
-                lambda c, cx: query_mod.ball_query(c, cx, cfg.radius, cfg.nsample)
-            )(flat_coords, cxyz)
+            )  # (B·T, k) local
+            cidx = jnp.take_along_axis(flat_tiles, local_c, axis=1)  # global
+            cxyz = jnp.take_along_axis(flat_coords, local_c[..., None], axis=1)
 
-        # local tile slots -> global point indices
-        nidx = jnp.take_along_axis(flat_tiles[:, None, :], nbrs_local.idx, axis=2)
+        with jax.named_scope("query"):
+            if cfg.resolved_query == "lattice":
+                nbrs_local = lattice_query_tiles(
+                    flat_coords, cxyz, cfg.radius, cfg.nsample,
+                    backend=cfg.backend, interpret=cfg.interpret,
+                )
+            else:  # per-tile ball query: no kernel counterpart, XLA path
+                nbrs_local = jax.vmap(
+                    lambda c, cx: query_mod.ball_query(c, cx, cfg.radius, cfg.nsample)
+                )(flat_coords, cxyz)
+
+        with jax.named_scope("group"):
+            # local tile slots -> global point indices
+            nidx = jnp.take_along_axis(flat_tiles[:, None, :], nbrs_local.idx, axis=2)
 
         m = t * k
         return PreprocessResult(

@@ -11,6 +11,15 @@ PC2IM switches, all config-selectable (benchmarked in fig12a/fig13):
   quant      : "none" | "sc_w16a16" (C4; applies to every MLP linear via the
                ExecutionPolicy threaded through forward — see core/policy.py)
 
+Every stage runs under a `jax.named_scope`, so each HLO op's `op_name`
+metadata names its layer (the compiled program is otherwise unchanged):
+`sa{i}/partition`, `sa{i}/fps`, `sa{i}/query` (the engine's stages, see
+core/engine.py), `sa{i}/group` (slot -> index remap, feature gathers,
+masked max-pool), `sa{i}/mlp`, `global/mlp`, `global/pool`, `head`, and
+`fp{i}/knn`, `fp{i}/interp`, `fp{i}/mlp`; SA and FP stages count from 1,
+FP from the coarsest.  The benchmark's trace readers map a compiled
+program's instruction names to these paths (bench/benchlib/scopes.py).
+
 Note on delayed aggregation: standard SA feeds the MLP relative coordinates
 (neighbour - centroid), which cannot be precomputed per point.  Following
 Mesorasi [8] (which the paper adopts), the delayed path feeds *absolute*
@@ -149,9 +158,10 @@ def preprocess_stage(
     # under jax.grad-less loops) keep each stage's own compiled engine
     traced = isinstance(xyz, jax.core.Tracer)
     results = []
-    for sa_cfg in cfg.sa:
+    for i, sa_cfg in enumerate(cfg.sa, 1):
         engine = stage_engine(cfg, sa_cfg, xyz.shape[-2], policy)
-        res = engine.raw(xyz) if traced else engine(xyz)
+        with jax.named_scope(f"sa{i}"):
+            res = engine.raw(xyz) if traced else engine(xyz)
         results.append(res)
         xyz = res.centroid_xyz
     return tuple(results)
@@ -175,16 +185,21 @@ def feature_stage(
     feats = points[..., 3:] if cfg.in_features else None
 
     levels = [(xyz, feats)]
-    for sa_cfg, mlp_p, res in zip(cfg.sa, params["sa"], preproc):
+    for i, (sa_cfg, mlp_p, res) in enumerate(zip(cfg.sa, params["sa"], preproc), 1):
         xyz_i, feats_i = levels[-1]
-        levels.append(_sa_stage(cfg, sa_cfg, mlp_p, xyz_i, feats_i, policy, res=res))
+        with jax.named_scope(f"sa{i}"):
+            levels.append(_sa_stage(cfg, sa_cfg, mlp_p, xyz_i, feats_i, policy, res=res))
 
     if cfg.task == "cls":
         xyz_l, feats_l = levels[-1]
-        x = jnp.concatenate([xyz_l, feats_l], axis=-1)  # (B, M, C)
-        x = nn.mlp_apply(params["global"], x, policy=policy)
-        x = jnp.max(x, axis=1)  # global max pool per cloud
-        return nn.mlp_apply(params["head"], x, final_act=False, policy=policy)
+        with jax.named_scope("global"):
+            with jax.named_scope("mlp"):
+                x = jnp.concatenate([xyz_l, feats_l], axis=-1)  # (B, M, C)
+                x = nn.mlp_apply(params["global"], x, policy=policy)
+            with jax.named_scope("pool"):
+                x = jnp.max(x, axis=1)  # global max pool per cloud
+        with jax.named_scope("head"):
+            return nn.mlp_apply(params["head"], x, final_act=False, policy=policy)
 
     # segmentation: FP stages walk the pyramid back from coarse to fine.
     # Skip channels (mirrors init_params): intermediate levels contribute
@@ -193,17 +208,22 @@ def feature_stage(
     n_fp = len(params["fp"])
     for i, fp_p in enumerate(params["fp"]):
         fine_xyz, fine_f = levels[n_fp - 1 - i]
-        idx, dist = jax.vmap(lambda q, r: Q.knn(q, r, 3))(fine_xyz, coarse_xyz)
-        w = Q.three_nn_interpolate_weights(dist)
-        interp = jax.vmap(G.interpolate_features)(coarse_f, idx, w)  # (B, Nf, Cc)
-        if i == n_fp - 1:  # finest level: raw inputs as skip
-            skip = fine_xyz if fine_f is None else jnp.concatenate([fine_xyz, fine_f], -1)
-        else:
-            skip = fine_f
-        x = jnp.concatenate([interp, skip], axis=-1)
-        coarse_f = nn.mlp_apply(fp_p, x, policy=policy)
+        with jax.named_scope(f"fp{i + 1}"):
+            with jax.named_scope("knn"):
+                idx, dist = jax.vmap(lambda q, r: Q.knn(q, r, 3))(fine_xyz, coarse_xyz)
+            with jax.named_scope("interp"):
+                w = Q.three_nn_interpolate_weights(dist)
+                interp = jax.vmap(G.interpolate_features)(coarse_f, idx, w)  # (B, Nf, Cc)
+                if i == n_fp - 1:  # finest level: raw inputs as skip
+                    skip = fine_xyz if fine_f is None else jnp.concatenate([fine_xyz, fine_f], -1)
+                else:
+                    skip = fine_f
+                x = jnp.concatenate([interp, skip], axis=-1)
+            with jax.named_scope("mlp"):
+                coarse_f = nn.mlp_apply(fp_p, x, policy=policy)
         coarse_xyz = fine_xyz
-    return nn.mlp_apply(params["head"], coarse_f, final_act=False, policy=policy)
+    with jax.named_scope("head"):
+        return nn.mlp_apply(params["head"], coarse_f, final_act=False, policy=policy)
 
 
 def _sa_stage(cfg, sa_cfg, mlp_params, xyz, feats, policy, res=None):
@@ -220,20 +240,24 @@ def _sa_stage(cfg, sa_cfg, mlp_params, xyz, feats, policy, res=None):
     nbrs = res.neighbors
     if cfg.aggregation == "delayed":
         # C5: per-POINT mlp on [abs-xyz, feats], then gather + masked maxpool
-        x = xyz if feats is None else jnp.concatenate([xyz, feats], axis=-1)
-        pointwise = nn.mlp_apply(mlp_params, x, policy=policy)  # (B, N, C')
-        grouped = jax.vmap(G.group_features)(pointwise, nbrs)  # (B, M, S, C')
-        new_feats = G.masked_maxpool(grouped, nbrs.mask)
+        with jax.named_scope("mlp"):
+            x = xyz if feats is None else jnp.concatenate([xyz, feats], axis=-1)
+            pointwise = nn.mlp_apply(mlp_params, x, policy=policy)  # (B, N, C')
+        with jax.named_scope("group"):
+            grouped = jax.vmap(G.group_features)(pointwise, nbrs)  # (B, M, S, C')
+            new_feats = G.masked_maxpool(grouped, nbrs.mask)
     else:
-        rel = jax.vmap(G.group_relative_coords)(xyz, res.centroid_xyz, nbrs)
-        if feats is None:
-            grouped = rel
-        else:
-            gf = jax.vmap(G.group_features)(feats, nbrs)  # (B, M, S, C)
-            grouped = jnp.concatenate([rel, gf], axis=-1)
-        new_feats = G.masked_maxpool(
-            nn.mlp_apply(mlp_params, grouped, policy=policy), nbrs.mask
-        )
+        with jax.named_scope("group"):
+            rel = jax.vmap(G.group_relative_coords)(xyz, res.centroid_xyz, nbrs)
+            if feats is None:
+                grouped = rel
+            else:
+                gf = jax.vmap(G.group_features)(feats, nbrs)  # (B, M, S, C)
+                grouped = jnp.concatenate([rel, gf], axis=-1)
+        with jax.named_scope("mlp"):
+            pointwise = nn.mlp_apply(mlp_params, grouped, policy=policy)
+        with jax.named_scope("group"):
+            new_feats = G.masked_maxpool(pointwise, nbrs.mask)
     return res.centroid_xyz, new_feats
 
 

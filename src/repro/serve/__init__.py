@@ -12,8 +12,9 @@ enter the feature stage directly.  The SLO control plane sits on top:
 (replica rejoin + queue-depth/cost-signal scaling) and `chaos`
 (deterministic fault injection for recovery tests).  `trace` / `obs` are
 the observability layer: a ring-buffered lifecycle tracer every component
-reports into, and the reductions/exporters (stage breakdown, Chrome-trace
-JSON, Prometheus text — live via `MetricsServer`) built on it.  `adapt`
+reports into, stage spans on the JAX profiler's clock, and the
+reductions/exporters (stage breakdown, Prometheus text — live via
+`MetricsServer`) built on it.  `adapt`
 closes the loop from observation back to the knobs: the
 `AdaptiveController` retunes buckets / max_batch / batching patience
 through the runtime's pause-free `reconfigure` path.  `pointcloud` /
@@ -76,9 +77,7 @@ from repro.serve.obs import (  # noqa: F401
     prometheus_text,
     request_timelines,
     stage_breakdown,
-    to_chrome_trace,
     trace_problems,
-    write_chrome_trace,
 )
 from repro.serve.slo import BULK, DEFAULT, INTERACTIVE, SLOClass  # noqa: F401
 from repro.serve.trace import (  # noqa: F401

@@ -39,6 +39,7 @@ tests and the serve_slo benchmark drive.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -58,6 +59,7 @@ from repro.launch.mesh import carve_device_groups, make_replica_mesh
 from repro.runtime.fault_tolerance import HeartbeatMonitor, StragglerMonitor
 from repro.serve.metrics import BatchRecord, ServeMetrics
 from repro.serve.queue import try_set_exception, try_set_result
+from repro.serve.trace import span
 
 
 class NoReplicaAvailable(RuntimeError):
@@ -295,6 +297,17 @@ class ReplicaPool:
         tr = self.tracer
         if tr is not None and mb.batch_id != -1:
             tr.emit(name, batch_id=mb.batch_id, replica_id=rep_id, args=args)
+
+    def _span(self, name: str, mb, rep: Replica):
+        """A stage span of one batch on one replica (see `trace.span`)."""
+        return span(name, self.tracer, batch_id=mb.batch_id, replica_id=rep.id)
+
+    def _fetch(self, mb, rep: Replica, out) -> np.ndarray:
+        """Wait for a launched program's result, then copy it to the host."""
+        with self._span("batch.wait", mb, rep):
+            jax.block_until_ready(out)
+        with self._span("batch.d2h", mb, rep):
+            return np.asarray(out)
 
     def _start_liveness(self, rep: Replica) -> None:
         """Attach heartbeat monitors + pumps to one replica (when enabled)."""
@@ -596,20 +609,21 @@ class ReplicaPool:
         try:
             accel = get_accelerator(self.model_cfg, mb.policy)
             rep.straggler.step_start()
-            batch = jax.device_put(jnp.asarray(mb.batch), rep.device)
+            with self._span("batch.h2d", mb, rep):
+                batch = jax.device_put(jnp.asarray(mb.batch), rep.device)
             if mb.cache is not None:
                 logits, skipped = self._run_cached(accel, rep, mb, batch)
             else:
-                self._emit("batch.execute_start", mb, rep_id=rep.id)
-                logits = np.asarray(
-                    jax.block_until_ready(accel.infer(rep.params, batch))
-                )
-                self._emit("batch.execute_end", mb, rep_id=rep.id)
+                with self._span("batch.execute", mb, rep):
+                    with self._span("batch.launch", mb, rep):
+                        out = accel.infer(rep.params, batch)
+                    logits = self._fetch(mb, rep, out)
                 skipped = False
             dt = rep.straggler.step_end(rep.n_batches)
             if rep.heartbeat is not None:
                 rep.heartbeat.beat()
-            self._record_success(rep, entry, logits, dt, preprocess_skipped=skipped)
+            with self._span("batch.complete", mb, rep):
+                self._record_success(rep, entry, logits, dt, preprocess_skipped=skipped)
         except Exception as e:  # noqa: BLE001 — any device/kernel failure
             # retry only if the entry was still ours: a concurrent evict()
             # already cleared inflight AND re-dispatched it — retrying here
@@ -636,17 +650,17 @@ class ReplicaPool:
             accel = get_accelerator(self.model_cfg, mb.policy)
             arts = accel.mesh_artifacts(rep.devices)
             rep.straggler.step_start()
-            self._emit("batch.execute_start", mb, rep_id=rep.id)
-            logits = np.asarray(
-                jax.block_until_ready(
-                    arts.infer(rep.mesh_params, jnp.asarray(mb.batch))
-                )
-            )
-            self._emit("batch.execute_end", mb, rep_id=rep.id)
+            with self._span("batch.execute", mb, rep):
+                with self._span("batch.h2d", mb, rep):
+                    points = jnp.asarray(mb.batch)
+                with self._span("batch.launch", mb, rep):
+                    out = arts.infer(rep.mesh_params, points)
+                logits = self._fetch(mb, rep, out)
             dt = rep.straggler.step_end(rep.n_batches)
             if rep.heartbeat is not None:
                 rep.heartbeat.beat()
-            self._record_success(rep, entry, logits, dt)
+            with self._span("batch.complete", mb, rep):
+                self._record_success(rep, entry, logits, dt)
         except Exception as e:  # noqa: BLE001 — any device/kernel failure
             with self._lock:
                 was_inflight = rep.inflight.pop(entry.seq, None) is not None
@@ -726,57 +740,52 @@ class ReplicaPool:
             )
             jax.block_until_ready(fused)
             return logits, False
-        self._emit("batch.cache_start", mb, rep_id=rep.id)
-        entries = self._resolve_entries(mb)
-        n_hits = sum(1 for e in entries if e is not None)
-        if n_hits == mb.n_real:
-            # device_put: the feature artifact must only ever see COMMITTED
-            # device trees — a host-numpy variant would compile a second
-            # executable for the same shapes (a one-off multi-hundred-ms
-            # stall mid-traffic).  Pre-staged entries (warm rejoin) stack
-            # device-side and skip the host restack + transfer entirely
-            pre = self._staged_stack(rep, entries, mb.batch.shape[0])
-            if pre is None:
-                pre = jax.device_put(
-                    result_stack([e.pre for e in entries], total=mb.batch.shape[0]),
-                    rep.device,
-                )
-            self._emit("batch.cache_end", mb, rep_id=rep.id,
-                       args={"hits": n_hits, "skip": True})
-            self._emit("batch.feature_start", mb, rep_id=rep.id)
-            logits = np.asarray(
-                jax.block_until_ready(
-                    accel.feature_from_cached(rep.params, batch, pre)
-                )
-            )
-            self._emit("batch.feature_end", mb, rep_id=rep.id)
+        with self._span("batch.cache", mb, rep) as end:
+            entries = self._resolve_entries(mb)
+            n_hits = sum(1 for e in entries if e is not None)
+            end["hits"] = n_hits
+            all_hit = n_hits == mb.n_real
+            if all_hit:
+                end["skip"] = True
+                # device_put: the feature artifact must only ever see COMMITTED
+                # device trees — a host-numpy variant would compile a second
+                # executable for the same shapes (a one-off multi-hundred-ms
+                # stall mid-traffic).  Pre-staged entries (warm rejoin) stack
+                # device-side and skip the host restack + transfer entirely
+                pre = self._staged_stack(rep, entries, mb.batch.shape[0])
+                if pre is None:
+                    pre = jax.device_put(
+                        result_stack([e.pre for e in entries], total=mb.batch.shape[0]),
+                        rep.device,
+                    )
+        if all_hit:
+            with self._span("batch.feature", mb, rep):
+                with self._span("batch.launch", mb, rep):
+                    out = accel.feature_from_cached(rep.params, batch, pre)
+                logits = self._fetch(mb, rep, out)
             return logits, True
-        self._emit("batch.cache_end", mb, rep_id=rep.id, args={"hits": n_hits})
         if n_hits == 0:
-            self._emit("batch.execute_start", mb, rep_id=rep.id)
-            logits_dev, pre = accel.infer_with_preprocess(rep.params, batch)
-            logits = np.asarray(jax.block_until_ready(logits_dev))
-            self._emit("batch.execute_end", mb, rep_id=rep.id)
+            with self._span("batch.execute", mb, rep):
+                with self._span("batch.launch", mb, rep):
+                    logits_dev, pre = accel.infer_with_preprocess(rep.params, batch)
+                logits = self._fetch(mb, rep, logits_dev)
             self._insert_executor.submit(self._insert_misses, mb, pre, entries)
             return logits, False
         # mixed: block on the preprocess result explicitly (result_to_host is
         # a no-op copy on the already-host tree inside _cached_splice), so the
         # preprocess span measures the stage compute and the splice span only
         # the host row surgery + cache fill
-        self._emit("batch.preprocess_start", mb, rep_id=rep.id)
-        pre_host = result_to_host(accel.preprocess_stage(batch))
-        self._emit("batch.preprocess_end", mb, rep_id=rep.id)
-        self._emit("batch.splice_start", mb, rep_id=rep.id)
-        pre = jax.device_put(
-            self._cached_splice(mb, pre_host, entries),
-            rep.device,
-        )
-        self._emit("batch.splice_end", mb, rep_id=rep.id)
-        self._emit("batch.feature_start", mb, rep_id=rep.id)
-        logits = np.asarray(
-            jax.block_until_ready(accel.feature_stage(rep.params, batch, pre))
-        )
-        self._emit("batch.feature_end", mb, rep_id=rep.id)
+        with self._span("batch.preprocess", mb, rep):
+            pre_host = result_to_host(accel.preprocess_stage(batch))
+        with self._span("batch.splice", mb, rep):
+            pre = jax.device_put(
+                self._cached_splice(mb, pre_host, entries),
+                rep.device,
+            )
+        with self._span("batch.feature", mb, rep):
+            with self._span("batch.launch", mb, rep):
+                out = accel.feature_stage(rep.params, batch, pre)
+            logits = self._fetch(mb, rep, out)
         return logits, False
 
     def _splice_or_insert(self, rep, mb, pre, entries):
@@ -893,42 +902,42 @@ class ReplicaPool:
             accel = get_accelerator(self.model_cfg, mb.policy)
             rep.acquire_handoff()  # double-buffer bound (released by feature stage)
             try:
-                batch = jax.device_put(jnp.asarray(mb.batch), rep.device)
-                entries: tuple = ()
+                with self._span("batch.h2d", mb, rep):
+                    batch = jax.device_put(jnp.asarray(mb.batch), rep.device)
+                skipped = False
                 if mb.cache is not None:
                     # resolved on the worker thread: the pipelined worker runs
                     # one batch ahead of the feature thread, so late hits from
                     # the immediately preceding batch's insert may still miss
                     # — correctness is unaffected, only the skip opportunity
-                    self._emit("batch.cache_start", mb, rep_id=rep.id)
-                    entries = self._resolve_entries(mb)
-                if mb.n_real > 0 and entries and all(e is not None for e in entries):
-                    # cache skip composes with the pipeline: the worker hands
-                    # the restacked payload straight to the feature thread —
-                    # no preprocess dispatch at all for this batch
-                    # (device_put: committed, same executable as miss batches;
-                    # pre-staged entries stack device-side, no host restack)
-                    pre = self._staged_stack(rep, entries, mb.batch.shape[0])
-                    if pre is None:
-                        pre = jax.device_put(
-                            result_stack(
-                                [e.pre for e in entries], total=mb.batch.shape[0]
-                            ),
-                            rep.device,
-                        )
-                    self._emit("batch.cache_end", mb, rep_id=rep.id,
-                               args={"skip": True})
-                    skipped = True
+                    with self._span("batch.cache", mb, rep) as end:
+                        entries = self._resolve_entries(mb)
+                        skipped = mb.n_real > 0 and all(e is not None for e in entries)
+                        if skipped:
+                            end["skip"] = True
+                            # cache skip composes with the pipeline: the worker
+                            # hands the restacked payload straight to the feature
+                            # thread — no preprocess dispatch at all for this
+                            # batch (device_put: committed, same executable as
+                            # miss batches; pre-staged entries stack
+                            # device-side, no host restack)
+                            pre = self._staged_stack(rep, entries, mb.batch.shape[0])
+                            if pre is None:
+                                pre = jax.device_put(
+                                    result_stack(
+                                        [e.pre for e in entries], total=mb.batch.shape[0]
+                                    ),
+                                    rep.device,
+                                )
                 else:
-                    if mb.cache is not None:
-                        self._emit("batch.cache_end", mb, rep_id=rep.id)
+                    entries = ()
+                if not skipped:
                     # async — the span measures the dispatch only; the stage's
                     # device time is charged to the feature span through the
                     # data dependency (block_until_ready)
-                    self._emit("batch.preprocess_start", mb, rep_id=rep.id)
-                    pre = accel.preprocess_stage(batch)  # async — hand off, don't block
-                    self._emit("batch.preprocess_end", mb, rep_id=rep.id)
-                    skipped = False
+                    with self._span("batch.preprocess", mb, rep):
+                        with self._span("batch.launch", mb, rep):
+                            pre = accel.preprocess_stage(batch)  # hand off, don't block
                 if rep.heartbeat is not None:
                     rep.heartbeat.beat()
                 rep.submit_feature(
@@ -975,23 +984,21 @@ class ReplicaPool:
                         # the transfer, same data dependency); all-miss
                         # batches keep the device tree + async insert
                         mixed = any(e is not None for e in entries)
-                        if mixed:
-                            self._emit("batch.splice_start", mb, rep_id=rep.id)
-                        pre = self._splice_or_insert(rep, mb, pre, entries)
-                        if mixed:
-                            self._emit("batch.splice_end", mb, rep_id=rep.id)
+                        with (self._span("batch.splice", mb, rep) if mixed
+                              else contextlib.nullcontext()):
+                            pre = self._splice_or_insert(rep, mb, pre, entries)
                     feature = accel.feature_stage
-                self._emit("batch.feature_start", mb, rep_id=rep.id)
-                logits = np.asarray(
-                    jax.block_until_ready(feature(rep.params, batch, pre))
-                )
-                self._emit("batch.feature_end", mb, rep_id=rep.id)
+                with self._span("batch.feature", mb, rep):
+                    with self._span("batch.launch", mb, rep):
+                        out = feature(rep.params, batch, pre)
+                    logits = self._fetch(mb, rep, out)
                 dt = time.monotonic() - t0
                 if rep.feature_heartbeat is not None:
                     rep.feature_heartbeat.beat()
-                self._record_success(
-                    rep, entry, logits, dt, preprocess_skipped=skipped
-                )
+                with self._span("batch.complete", mb, rep):
+                    self._record_success(
+                        rep, entry, logits, dt, preprocess_skipped=skipped
+                    )
             except Exception as e:  # noqa: BLE001 — any device/kernel failure
                 with self._lock:
                     was_inflight = rep.inflight.pop(entry.seq, None) is not None

@@ -18,11 +18,12 @@ periodic :class:`Reporter` without touching the hot path:
 * :func:`batch_crosscheck` — reconcile batch spans against the
   independently-timed ``BatchRecord.duration_s`` wall-clock, keyed by the
   ``batch_id`` both sides carry.
-* :func:`to_chrome_trace` / :func:`write_chrome_trace` — Chrome-trace /
-  Perfetto JSON: request lanes, batch lanes with stage slices, and a
-  control-plane lane, all on one shared clock.
 * :func:`prometheus_text` — Prometheus text exposition of a
   :class:`~repro.serve.metrics.MetricsSnapshot`.
+
+A timeline of the serving stages beside the device ops comes from the JAX
+profiler instead: the stage spans of :func:`repro.serve.trace.span` are
+profiler annotations (see examples/serve_trace.py).
 
 Two stateful exporters live at the end: :class:`Reporter`, a daemon thread
 on :class:`~repro.serve.runtime.ServingRuntime` that periodically snapshots
@@ -34,7 +35,6 @@ the metrics and hands a one-line summary to a sink, and
 from __future__ import annotations
 
 import dataclasses
-import json
 import sys
 import threading
 
@@ -331,120 +331,6 @@ def batch_crosscheck(
             )
         )
     return out
-
-
-# -- Chrome trace / Perfetto export -------------------------------------------
-
-_PID_REQUESTS = 1
-_PID_BATCHES = 2
-_PID_CONTROL = 3
-
-
-def to_chrome_trace(events: list[TraceEvent]) -> dict:
-    """Render a trace snapshot as a Chrome-trace (Perfetto-loadable) object.
-
-    Three process lanes share one clock: ``requests`` (one thread row per
-    trace id — a complete "X" slice from submit to terminal plus instant
-    marks for every edge), ``batches`` (one row per batch id — "X" slices
-    per execution stage plus assembly/dispatch/retry instants) and
-    ``control-plane`` (one row per replica — eviction/rejoin/scale/chaos/
-    cache instants).  Timestamps are microseconds of ``time.monotonic``;
-    load the JSON in https://ui.perfetto.dev or chrome://tracing.
-    """
-    out: list[dict] = [
-        {
-            "ph": "M",
-            "pid": pid,
-            "name": "process_name",
-            "args": {"name": label},
-        }
-        for pid, label in (
-            (_PID_REQUESTS, "requests"),
-            (_PID_BATCHES, "batches"),
-            (_PID_CONTROL, "control-plane"),
-        )
-    ]
-    timelines = request_timelines(events)
-    for tl in timelines.values():
-        submit = _first(list(tl.events), "request.submit")
-        if submit is not None and tl.e2e_s is not None:
-            out.append(
-                {
-                    "ph": "X",
-                    "pid": _PID_REQUESTS,
-                    "tid": tl.trace_id,
-                    "name": f"{tl.terminal} [{tl.slo}]",
-                    "ts": submit.t * 1e6,
-                    "dur": tl.e2e_s * 1e6,
-                    "args": {"batch_id": tl.batch_id, **tl.stages},
-                }
-            )
-        for ev in tl.events:
-            out.append(
-                {
-                    "ph": "i",
-                    "pid": _PID_REQUESTS,
-                    "tid": tl.trace_id,
-                    "name": ev.name,
-                    "ts": ev.t * 1e6,
-                    "s": "t",
-                    "args": ev.args or {},
-                }
-            )
-    by_batch: dict[int, list[TraceEvent]] = {}
-    for ev in events:
-        if ev.name.startswith("batch.") and ev.batch_id != -1:
-            by_batch.setdefault(ev.batch_id, []).append(ev)
-    for bid, bevs in by_batch.items():
-        for stage, (t0, t1) in _stage_pairs(bevs).items():
-            out.append(
-                {
-                    "ph": "X",
-                    "pid": _PID_BATCHES,
-                    "tid": bid,
-                    "name": stage,
-                    "ts": t0 * 1e6,
-                    "dur": (t1 - t0) * 1e6,
-                }
-            )
-        for ev in bevs:
-            if ev.name.endswith(("_start", "_end")):
-                continue  # already rendered as an "X" slice above
-            out.append(
-                {
-                    "ph": "i",
-                    "pid": _PID_BATCHES,
-                    "tid": bid,
-                    "name": ev.name,
-                    "ts": ev.t * 1e6,
-                    "s": "t",
-                    "args": ev.args or {},
-                }
-            )
-    for ev in events:
-        scope = ev.name.partition(".")[0]
-        if scope in ("request", "batch"):
-            continue
-        out.append(
-            {
-                "ph": "i",
-                "pid": _PID_CONTROL,
-                "tid": max(0, ev.replica_id),
-                "name": ev.name,
-                "ts": ev.t * 1e6,
-                "s": "p",
-                "args": ev.args or {},
-            }
-        )
-    return {"traceEvents": out, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(path, events: list[TraceEvent]) -> int:
-    """Write `to_chrome_trace(events)` as JSON at `path`; returns event count."""
-    doc = to_chrome_trace(events)
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return len(doc["traceEvents"])
 
 
 # -- Prometheus text exposition -----------------------------------------------

@@ -60,6 +60,7 @@ from repro.serve.queue import (
     try_set_exception,
     try_set_result,
 )
+from repro.serve.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -478,9 +479,18 @@ class BatchScheduler:
                     ent.row if ent is not None else req.fitted
                     for req, ent in zip(live, entries)
                 ]
-            batch = assemble_batch(
-                live, bucket, self.width, cfg.max_batch, rows=rows
-            )
+            with span("batch.assemble"):
+                mb = MicroBatch(
+                    requests=tuple(live),
+                    bucket=bucket,
+                    policy=policy,
+                    batch=assemble_batch(
+                        live, bucket, self.width, cfg.max_batch, rows=rows
+                    ),
+                    cache=cache,
+                    cache_entries=entries,
+                    batch_id=self.tracer.next_batch_id() if self.tracer is not None else -1,
+                )
         except Exception as e:  # noqa: BLE001 — one bad cloud fails ITS batch only
             self.metrics.record_failed(len(live))
             for req in live:
@@ -490,15 +500,6 @@ class BatchScheduler:
                         "request.failed", trace_id=req.trace_id, slo=req.slo.name
                     )
             return
-        mb = MicroBatch(
-            requests=tuple(live),
-            bucket=bucket,
-            policy=policy,
-            batch=batch,
-            cache=cache,
-            cache_entries=entries,
-            batch_id=self.tracer.next_batch_id() if self.tracer is not None else -1,
-        )
         if self.tracer is not None:
             self.tracer.emit(
                 "batch.assembled",

@@ -13,14 +13,26 @@ export shows the request timeline against the events that shaped it.
 Design constraints, in order:
 
 * **Off is free.**  Components hold ``tracer: Tracer | None`` and every
-  instrumentation site is a single ``if tracer is not None`` branch — no
+  ring event site is a single ``if tracer is not None`` branch — no
   event objects, no lock traffic, nothing allocated when tracing is off.
+  The profiler spans of :func:`span` stay open: a few per batch, at about
+  a microsecond each when no profiler session runs.
 * **On is cheap.**  ``emit`` builds one small frozen dataclass and appends
   it to a ``deque(maxlen=capacity)`` under one uncontended lock; the ring
   silently drops the oldest events instead of growing or blocking.
 * **The event namespace is closed.**  Every event name is declared exactly
   once in :data:`EVENTS`; ``emit`` rejects undeclared names and a tier-1
   test greps the serve sources to keep call sites and registry in sync.
+
+Stage spans go through one helper, :func:`span`: it opens a
+``jax.profiler.TraceAnnotation`` named after the stage, with the replica id,
+so a profiler trace shows the stage on the host plane on the same clock as
+the device ops (about a microsecond per span when no profiler runs), and,
+with a ring tracer attached, emits the stage's ``<name>_start`` /
+``<name>_end`` ring events as well.  The nested stages of one batch's
+turnaround (``batch.h2d``, ``batch.launch``, ``batch.wait``, ``batch.d2h``,
+``batch.complete``, ``batch.assemble``) are profiler spans only: they are no
+stages of :func:`repro.serve.obs.request_timelines`.
 
 Sampling is head-based and per trace id: :meth:`Tracer.new_trace` decides
 once, at submit, whether a request is traced (``None`` means sampled out)
@@ -32,10 +44,13 @@ the frame of reference the sampled requests hang off.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import threading
 import time
+
+import jax
 
 # --------------------------------------------------------------------------
 # Event-name registry.  CLOSED: every name emitted anywhere in repro.serve
@@ -94,9 +109,28 @@ EVENTS: tuple[str, ...] = (
     "adapt.propose",
     "adapt.apply",
     "adapt.rollback",
+    # profiler spans (see `span`); the first five also have ring pairs above
+    "batch.execute",
+    "batch.cache",
+    "batch.preprocess",
+    "batch.splice",
+    "batch.feature",
+    "batch.assemble",
+    "batch.h2d",
+    "batch.launch",
+    "batch.wait",
+    "batch.d2h",
+    "batch.complete",
 )
 
 _EVENT_SET = frozenset(EVENTS)
+
+#: Span name -> its (start, end) ring events, for the spans that have them.
+RING_PAIRS = {
+    name: (name + "_start", name + "_end")
+    for name in EVENTS
+    if name + "_start" in _EVENT_SET
+}
 
 #: The five mutually-exclusive ways a request span ends.  A well-formed
 #: trace contains exactly one of these per trace id (asserted in tests and
@@ -144,6 +178,29 @@ class TraceEvent:
     replica_id: int = -1
     slo: str = ""
     args: dict | None = None
+
+
+@contextlib.contextmanager
+def span(name: str, tracer: Tracer | None = None, *, batch_id: int = -1,
+         replica_id: int = -1):
+    """One serving stage: a profiler span, plus its ring pair where it has one.
+
+    ``name`` must be declared in EVENTS.  The ring events are emitted only
+    with a tracer and a real batch (warmup batches carry ``batch_id == -1``);
+    the end event only when the block returns normally.  The block gets a
+    dict: what it puts there becomes the end event's ``args``.
+    """
+    if name not in _EVENT_SET:
+        raise ValueError(f"undeclared trace span {name!r}")
+    pair = RING_PAIRS.get(name) if tracer is not None and batch_id != -1 else None
+    if pair is not None:
+        tracer.emit(pair[0], batch_id=batch_id, replica_id=replica_id)
+    end_args: dict = {}
+    with jax.profiler.TraceAnnotation(name, replica=replica_id):
+        yield end_args
+    if pair is not None:
+        tracer.emit(pair[1], batch_id=batch_id, replica_id=replica_id,
+                    args=end_args or None)
 
 
 def _keep(trace_id: int, sample: float) -> bool:

@@ -191,7 +191,10 @@ class TestMixedPolicies:
         clear_cache()
         quant = ExecutionPolicy(quant="sc_w16a16")
         clouds = _clouds(8, seed=3)
-        rt = _runtime(cfg, params)
+        # the requests are submitted before start(): with the 5 ms default
+        # patience a slow first drain would find them past max_wait and
+        # flush half-full batches.  Only full batches may flush here.
+        rt = _runtime(cfg, params, max_wait_s=WAIT_S)
         futs = [
             rt.submit(c, policy=quant if i % 2 else None)
             for i, c in enumerate(clouds)

@@ -2,14 +2,14 @@
 registry, terminal-outcome completeness on a real runtime (every way a
 request can end yields exactly one terminal event on a monotonic span),
 chaos/evict/retry paths, sampling, the stage-attribution reductions, the
-Chrome-trace and Prometheus exporters, and the high-water-mark gauges.
+Prometheus exporter, the profiler stage spans, and the high-water-mark
+gauges.
 
 The integration tests reuse the SLO control-plane fixtures (real
 ServingRuntime on the smoke config); the reduction tests run on synthetic
 event streams with hand-picked timestamps so stage math is pinned exactly.
 """
 
-import json
 import re
 import threading
 import time
@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.core.accelerator import get_accelerator
+from repro.core.policy import ExecutionPolicy
 from repro.serve import (
     BULK,
     EVENTS,
@@ -44,11 +45,10 @@ from repro.serve import (
     prometheus_text,
     request_timelines,
     stage_breakdown,
-    to_chrome_trace,
     trace_problems,
-    write_chrome_trace,
 )
 from repro.serve.queue import AdmissionError
+from repro.serve.trace import RING_PAIRS, span
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -165,7 +165,7 @@ class TestEventRegistry:
     """The event namespace is closed: grep-enforced in both directions."""
 
     _LIT = re.compile(
-        r"""["']((?:request|batch|replica|scale|chaos|cache|adapt)\.[a-z_]+)["']"""
+        r"""["']((?:request|batch|replica|scale|chaos|cache|adapt)\.[a-z0-9_]+)["']"""
     )
 
     def _literals(self):
@@ -173,7 +173,9 @@ class TestEventRegistry:
         # rglob: subpackages (serve/adapt/) emit into the same registry
         for path in sorted(SERVE_DIR.rglob("*.py")):
             for name in self._LIT.findall(path.read_text()):
-                used.setdefault(name, set()).add(path.name)
+                # a stage span (trace.span) also emits its ring pair
+                for emitted in (name, *RING_PAIRS.get(name, ())):
+                    used.setdefault(emitted, set()).add(path.name)
         return used
 
     def test_every_emitted_name_is_declared(self):
@@ -625,32 +627,6 @@ class TestReductions:
 
 
 class TestExporters:
-    def test_chrome_trace_structure(self, tmp_path):
-        stream = _synthetic_stream() + [
-            TraceEvent("replica.evicted", 1.9, replica_id=1, args={"reason": "x"}),
-        ]
-        doc = to_chrome_trace(stream)
-        assert doc["displayTimeUnit"] == "ms"
-        evs = doc["traceEvents"]
-        meta = [e for e in evs if e["ph"] == "M"]
-        assert {m["args"]["name"] for m in meta} == {
-            "requests", "batches", "control-plane",
-        }
-        slices = [e for e in evs if e["ph"] == "X"]
-        req_slice = next(e for e in slices if e["pid"] == 1)
-        assert req_slice["dur"] == pytest.approx(0.75 * 1e6)
-        exec_slice = next(
-            e for e in slices if e["pid"] == 2 and e["name"] == "execute"
-        )
-        assert exec_slice["dur"] == pytest.approx(0.50 * 1e6)
-        control = [e for e in evs if e["pid"] == 3 and e["ph"] == "i"]
-        assert [c["name"] for c in control] == ["replica.evicted"]
-        # the file round-trips as JSON (Perfetto-loadable)
-        path = tmp_path / "trace.json"
-        n = write_chrome_trace(path, stream)
-        loaded = json.loads(path.read_text())
-        assert len(loaded["traceEvents"]) == n
-
     def test_prometheus_text_shape(self):
         m = ServeMetrics()
         m.record_submitted("interactive")
@@ -740,3 +716,76 @@ class TestTracingOff:
             rt.warmup()
             out = rt.submit(_clouds(1)[0]).result(timeout=WAIT_S)
         assert out.shape == (cfg.n_classes,)
+
+
+# -- profiler stage spans ------------------------------------------------------
+
+
+class TestStageSpans:
+    def test_span_rejects_undeclared_names(self):
+        with pytest.raises(ValueError, match="undeclared"):
+            with span("batch.teleport"):
+                pass
+
+    def test_span_with_a_ring_pair_emits_it_with_end_args(self):
+        tr = Tracer()
+        with span("batch.cache", tr, batch_id=3, replica_id=1) as end:
+            end["hits"] = 2
+        evs = tr.events()
+        assert [e.name for e in evs] == ["batch.cache_start", "batch.cache_end"]
+        assert all(e.batch_id == 3 and e.replica_id == 1 for e in evs)
+        assert evs[0].args is None and evs[1].args == {"hits": 2}
+
+    def test_profiler_only_spans_and_warmup_batches_emit_no_ring_events(self):
+        tr = Tracer()
+        with span("batch.h2d", tr, batch_id=3, replica_id=0):
+            pass
+        with span("batch.execute", tr, batch_id=-1, replica_id=0):
+            pass
+        with span("batch.execute"):  # no tracer attached
+            pass
+        assert tr.events() == []
+
+    def test_failed_block_emits_no_end_event(self):
+        tr = Tracer()
+        with pytest.raises(RuntimeError):
+            with span("batch.feature", tr, batch_id=5, replica_id=0):
+                raise RuntimeError("device fault")
+        assert [e.name for e in tr.events()] == ["batch.feature_start"]
+
+    @pytest.mark.parametrize("path", ["sequential", "pipelined", "cached"])
+    def test_served_batch_stages_on_the_profiler_host_plane(
+        self, cfg, params, tmp_path, path
+    ):
+        """A served batch's stage spans reach a CPU profiler session in order,
+        each carrying the replica id."""
+        from jax.profiler import ProfileData
+
+        policy = ExecutionPolicy(pipeline="pipelined") if path == "pipelined" else None
+        kw = {"cache_max_bytes": 1 << 24} if path == "cached" else {}
+        rt = _runtime(cfg, params, max_wait_s=WAIT_S, **kw)
+        rt.warmup(policies=(policy,))
+        with rt:
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                futs = [rt.submit(c, policy=policy) for c in _clouds(MAX_BATCH, seed=9)]
+                for f in futs:
+                    f.result(timeout=WAIT_S)
+            finally:
+                jax.profiler.stop_trace()
+        (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        spans = {}
+        for plane in ProfileData.from_file(str(xplane)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("batch."):
+                        spans.setdefault(e.name, []).append((e.start_ns, dict(e.stats)))
+        order = ["batch.assemble", "batch.h2d", "batch.launch", "batch.wait",
+                 "batch.d2h", "batch.complete"]
+        assert all(len(spans.get(n, ())) >= 1 for n in order), sorted(spans)
+        starts = [min(t for t, _ in spans[n]) for n in order]
+        assert starts == sorted(starts), dict(zip(order, starts))
+        for name in order[1:]:
+            assert {st.get("replica") for _, st in spans[name]} == {0}, name
+        ring = {"pipelined": "batch.feature", "cached": "batch.cache"}
+        assert ring.get(path, "batch.execute") in spans
