@@ -7,12 +7,15 @@ The default run serves the full-width `pointnet2-cls` config through the
 normal entry points (ServingRuntime -> ReplicaPool -> PC2IMAccelerator) with
 the Pallas kernels compiled by Mosaic, replays every served micro-batch
 through the plain-jnp XLA path on the same chip, and runs one full-width
-`pointnet2-seg` batch the same way.  Checks:
+`pointnet2-seg` batch the same way, at the S3DIS sizes of
+`pointnet2_sem_seg.py` (four SA and four FP levels).  Checks:
 
   * the backend is a TPU and "auto" resolves to compiled Pallas kernels;
-  * every served artifact's lowered text holds `tpu_custom_call`;
+  * every served artifact's lowered text holds `tpu_custom_call`, and the
+    seg artifact calls the fused 3-NN kernel `pc2im_knn3`;
   * every future resolves, with no retry, eviction, shed or rejection;
   * preprocess indices equal the XLA reference bit for bit;
+  * each FP stage's 3-NN indices and distances equal the XLA path's bits;
   * logits agree with it to 1e-4 x max|logit|.
 
 `--chips 4` runs only the multi-chip path (mesh replicas under "batch" and
@@ -28,6 +31,7 @@ to the process that first touches JAX.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import pathlib
 import sys
@@ -44,11 +48,24 @@ from repro.core.accelerator import get_accelerator  # noqa: E402
 from repro.core.policy import ExecutionPolicy  # noqa: E402
 from repro.kernels import registry  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.models import pointnet2 as PN  # noqa: E402
 from repro.serve.runtime import RuntimeConfig, ServingRuntime  # noqa: E402
 
 REL_TOL = 1e-4  # logits: |served - reference| <= REL_TOL * max|reference|
 SC = "sc_w16a16"
 CLOUD_SIZES = (700, 1024, 1500)  # pad, exact, subsample at the 1024 bucket
+#: pointnet2_sem_seg.py on 4096-point S3DIS blocks: four SA and four FP levels
+SEG_S3DIS = dataclasses.replace(
+    pointnet2_seg.CONFIG,
+    n_classes=13,
+    sa=(
+        PN.SAConfig(1024, 0.1, 32, (32, 32, 64)),
+        PN.SAConfig(256, 0.2, 32, (64, 64, 128)),
+        PN.SAConfig(64, 0.4, 32, (128, 128, 256)),
+        PN.SAConfig(16, 0.8, 32, (256, 256, 512)),
+    ),
+    fp_mlp=(256, 256, 256, 128),
+)
 
 
 class SmokeFailure(RuntimeError):
@@ -109,11 +126,16 @@ def xla_twin(policy: ExecutionPolicy | None) -> ExecutionPolicy:
     return ExecutionPolicy(quant=policy.quant if policy else "none", backend="xla")
 
 
-def assert_kernels_compiled(accel, params, batch_shape) -> None:
-    """The artifact's lowered text must call Mosaic kernels, not an XLA path."""
+def assert_kernels_compiled(accel, params, batch_shape, kernels=()) -> None:
+    """The artifact's lowered text must call Mosaic kernels, not an XLA path.
+
+    Each name in `kernels` must be among the Mosaic kernels it calls.
+    """
     spec = jax.ShapeDtypeStruct(batch_shape, jnp.float32)
     text = jax.jit(accel.infer).lower(params, spec).as_text()
     check("tpu_custom_call" in text, f"{accel!r}: no tpu_custom_call in artifact")
+    for name in kernels:
+        check(f'kernel_name = "{name}"' in text, f"{accel!r}: no {name} kernel in artifact")
 
 
 def serve_phase(cfg, params, policies, clouds, config: RuntimeConfig) -> dict:
@@ -182,6 +204,27 @@ def direct_phase(cfg, params, policy, batch) -> float:
     return err
 
 
+def fp_knn_phase(cfg, policy, batch) -> int:
+    """Each FP stage's 3-NN under `policy` equals the XLA path's, bit for bit.
+
+    The stages search the SA pyramid of `batch` (the XLA path's centroids),
+    finest last, as `feature_stage` does.  Returns the number of stages.
+    """
+    policy = get_accelerator(cfg, policy).policy
+    pre = get_accelerator(cfg, xla_twin(policy)).preprocess_stage(batch)
+    levels = [batch[..., :3]] + [r.centroid_xyz for r in pre]
+    fp_knn = jax.jit(PN.fp_knn, static_argnums=2)
+    for stage, i in enumerate(range(len(levels) - 1, 0, -1), 1):
+        got = fp_knn(levels[i - 1], levels[i], policy)
+        want = fp_knn(levels[i - 1], levels[i], xla_twin(policy))
+        for name, a, b in zip(("idx", "dist"), got, want):
+            check(
+                np.array_equal(np.asarray(a), np.asarray(b)),
+                f"{cfg.name} fp{stage}: 3-NN {name} differs from the XLA path",
+            )
+    return len(levels) - 1
+
+
 def report(label: str, results: dict[str, tuple[float, bool]]) -> None:
     """One line per policy key: the error bound and whether it is bitwise."""
     for key, (err, same) in sorted(results.items()):
@@ -213,9 +256,11 @@ def run_one_chip(seed: int) -> None:
         cfg, params, res["log"], lambda pol: get_accelerator(cfg, xla_twin(pol))
     ))
 
-    seg = pointnet2_seg.CONFIG
+    seg = SEG_S3DIS
     sparams = jax.jit(get_accelerator(seg).init)(jax.random.PRNGKey(seed + 1))
-    assert_kernels_compiled(get_accelerator(seg), sparams, (8, seg.n_points, 3))
+    assert_kernels_compiled(
+        get_accelerator(seg), sparams, (8, seg.n_points, 3), kernels=("pc2im_knn3",)
+    )
     sbatch = jax.random.uniform(
         jax.random.PRNGKey(seed + 2), (8, seg.n_points, 3), minval=-1.0, maxval=1.0
     )
@@ -226,6 +271,8 @@ def run_one_chip(seed: int) -> None:
         f"max|logit diff| / max|logit| = {err:.3e} vs XLA reference "
         f"({time.perf_counter() - t0:.1f} s incl. compile, smoke timing)"
     )
+    n_fp = fp_knn_phase(seg, None, sbatch)
+    print(f"seg FP 3-NN, {n_fp} stages: idx and dist bitwise-equal to the XLA path")
 
 
 def run_four_chips(seed: int) -> None:

@@ -20,6 +20,9 @@ it is the Mesorasi approximation, which PointNet++-style nets tolerate
 
 from __future__ import annotations
 
+import functools
+import operator
+
 import jax
 import jax.numpy as jnp
 
@@ -69,6 +72,9 @@ def interpolate_features(features: jax.Array, idx: jax.Array, weights: jax.Array
     """3-NN inverse-distance interpolation (FP layer up-sampling).
 
     features: (N, C) at the coarse level; idx/weights: (M, k) -> (M, C).
+    The k terms are summed in index order, as the weights are
+    (`query.three_nn_interpolate_weights`), so the bits do not depend on
+    how the compiler fuses the sum.
     """
-    gathered = jnp.take(features, idx, axis=0)  # (M, k, C)
-    return jnp.sum(gathered * weights[..., None], axis=1)
+    gathered = jnp.take(features, idx, axis=0) * weights[..., None]  # (M, k, C)
+    return functools.reduce(operator.add, [gathered[:, j] for j in range(gathered.shape[1])])

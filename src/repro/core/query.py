@@ -14,6 +14,8 @@ point-feature-propagation (up-sampling) layers.
 
 from __future__ import annotations
 
+import functools
+import operator
 from typing import NamedTuple
 
 import jax
@@ -114,7 +116,11 @@ def three_nn_interpolate_weights(dist_sq: jax.Array, eps: float = 1e-8) -> jax.A
     """Inverse-distance weights for 3-NN feature interpolation (FP layer).
 
     dist_sq: (..., k) — normalised over the trailing k axis, so batched
-    (B, M, k) inputs work unchanged.
+    (B, M, k) inputs work unchanged.  The k weights are summed in index
+    order with elementwise adds: a reduce's association order is the
+    compiler's choice, and on the TPU it differed between programs that
+    take the distances from the knn3 kernel and from `knn`.
     """
     w = 1.0 / (dist_sq + eps)
-    return w / jnp.sum(w, axis=-1, keepdims=True)
+    total = functools.reduce(operator.add, [w[..., j : j + 1] for j in range(w.shape[-1])])
+    return w / total

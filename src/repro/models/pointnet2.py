@@ -38,6 +38,7 @@ from repro.core import grouping as G
 from repro.core import query as Q
 from repro.core.engine import EngineConfig, clamp_depth, get_engine
 from repro.core.policy import ExecutionPolicy, resolve_policy
+from repro.kernels.knn3 import knn3
 from repro.models import nn
 
 
@@ -210,7 +211,7 @@ def feature_stage(
         fine_xyz, fine_f = levels[n_fp - 1 - i]
         with jax.named_scope(f"fp{i + 1}"):
             with jax.named_scope("knn"):
-                idx, dist = jax.vmap(lambda q, r: Q.knn(q, r, 3))(fine_xyz, coarse_xyz)
+                idx, dist = fp_knn(fine_xyz, coarse_xyz, policy)
             with jax.named_scope("interp"):
                 w = Q.three_nn_interpolate_weights(dist)
                 interp = jax.vmap(G.interpolate_features)(coarse_f, idx, w)  # (B, Nf, Cc)
@@ -224,6 +225,19 @@ def feature_stage(
         coarse_xyz = fine_xyz
     with jax.named_scope("head"):
         return nn.mlp_apply(params["head"], coarse_f, final_act=False, policy=policy)
+
+
+def fp_knn(fine_xyz: jax.Array, coarse_xyz: jax.Array, policy: ExecutionPolicy):
+    """3 nearest coarse points of every fine point: (B, Nf, 3), (B, Nc, 3) -> (idx, dist).
+
+    Runs the fused `knn3` kernel under the policy's backend: on the TPU one
+    `pc2im_knn3` call per FP stage, with the batch as a grid axis; off it
+    `core.query.knn`.  Both give the same bits (squared L2 distances summed
+    in one order, first index on ties).
+    """
+    return jax.vmap(
+        lambda q, r: knn3(q, r, backend=policy.backend, interpret=policy.interpret)
+    )(fine_xyz, coarse_xyz)
 
 
 def _sa_stage(cfg, sa_cfg, mlp_params, xyz, feats, policy, res=None):

@@ -61,3 +61,12 @@ def test_phases_at_smoke_size():
     sparams = jax.jit(get_accelerator(seg).init)(jax.random.PRNGKey(1))
     batch = jax.random.uniform(jax.random.PRNGKey(2), (2, seg.n_points, 3), minval=-1, maxval=1)
     assert cs.direct_phase(seg, sparams, ExecutionPolicy(**INTERPRETED), batch) <= cs.REL_TOL
+
+
+def test_fp_knn_phase_at_smoke_size():
+    """The FP 3-NN check walks every FP stage of the seg pyramid and passes."""
+    cs = _load_chip_smoke()
+    seg = pointnet2_seg.smoke_config()
+    batch = jax.random.uniform(jax.random.PRNGKey(3), (2, seg.n_points, 3), minval=-1, maxval=1)
+    assert cs.fp_knn_phase(seg, ExecutionPolicy(**INTERPRETED), batch) == len(seg.sa)
+    assert len(cs.SEG_S3DIS.sa) == len(cs.SEG_S3DIS.fp_mlp) == 4

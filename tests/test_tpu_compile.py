@@ -9,7 +9,8 @@ described, not attached, at the shapes the cls and seg configs run at B=8:
   * every SA stage's PreprocessEngine (MSP partition, FPS, lattice query
     and their lane padding);
   * sc_matmul at every feature layer's (M, K, N) of cls, W16A16 and W8A8;
-  * knn3 at every seg feature-propagation stage's (fine, coarse) sizes.
+  * knn3 at every seg feature-propagation stage's (fine, coarse) sizes;
+  * the whole served seg program, whose FP stages call knn3.
 
 Each compiled program must call a Mosaic kernel (`tpu_custom_call`).  The
 topology is described inside a fixture — never at import — because only one
@@ -17,17 +18,22 @@ process at a time may load the TPU library.
 """
 
 import os
+import pathlib
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import pointnet2_cls, pointnet2_seg
+from repro.core.accelerator import PC2IMAccelerator
 from repro.core.policy import ExecutionPolicy
 from repro.kernels.knn3.ops import knn3
 from repro.kernels.sc_matmul.ops import sc_matmul_op
 from repro.models import pointnet2 as PN
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 BATCH = 8
 CONFIGS = {"cls": pointnet2_cls.CONFIG, "seg": pointnet2_seg.CONFIG}
 COMPILED = ExecutionPolicy(backend="pallas", interpret=False)
@@ -122,3 +128,29 @@ def test_knn3_compiles(one_chip, n_fine, n_coarse):
     p = jax.ShapeDtypeStruct((BATCH, n_coarse, 3), jnp.float32, sharding=one_chip)
     fn = jax.vmap(lambda a, b: knn3(a, b, backend="pallas", interpret=False))
     assert "tpu_custom_call" in _compiled_text(fn, q, p)
+
+
+def test_seg_program_calls_knn3_under_fp_knn_scopes(one_chip):
+    """Each FP stage's 3-NN is one `pc2im_knn3` call, mapped to its `fp{i}/knn` scope.
+
+    The map is `bench/benchlib/scopes.hlo_op_scopes`, the one the
+    benchmark's `knn_ms` reads, so that metric keeps measuring the layer.
+    """
+    sys.path.insert(0, str(ROOT / "bench"))
+    from benchlib.scopes import hlo_op_scopes
+
+    cfg = CONFIGS["seg"]
+    accel = PC2IMAccelerator(cfg, COMPILED)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(accel.init, jax.random.PRNGKey(0)),
+    )
+    spec = jax.ShapeDtypeStruct((BATCH, cfg.n_points, 3), jnp.float32, sharding=one_chip)
+    text = accel.infer_program.lower(params, spec).compile().as_text()
+    calls = re.findall(
+        r'^\s*(?:ROOT\s+)?%(pc2im_knn3[\w.]*) = .*custom_call_target="tpu_custom_call"',
+        text, re.MULTILINE,
+    )
+    scopes = hlo_op_scopes(text)
+    stages = sorted(scopes[c].split("/")[:2] for c in calls)
+    assert stages == [[f"fp{i}", "knn"] for i in range(1, len(cfg.sa) + 1)]
