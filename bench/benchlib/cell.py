@@ -3,10 +3,13 @@
     python3 bench/run_cell.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell's configuration (`bench/configs/<config>.json`) gives the model's
-sizes, the execution policy and the comparison's limit; its traffic mix
-(`bench/traffic/<mix>.json`) gives the clients and the cloud sizes. The
-runtime keeps every batching knob at the program's default, with one
-replica per chip of the cell.
+sizes, the execution policy and the comparison's limit, and names the
+program adapter `bench/programs/<program>.py`, which turns the model's
+sizes into the program's configuration and lists its linears, and the
+plain reference `bench/reference/<reference>.py`. Its traffic mix
+(`bench/traffic/<mix>.json`) gives the clients, the cloud sizes and the
+per-point channels beyond xyz. The runtime keeps every batching knob at the
+program's default, with one replica per chip of the cell.
 
 With `--trace 0` the result carries the cell's end-to-end metrics; with
 `--trace 1` the JAX profiler records the last `TRACE_SLICE_S` seconds of
@@ -32,6 +35,7 @@ import sys
 import tempfile
 import threading
 import time
+from types import ModuleType
 
 import numpy as np
 
@@ -58,6 +62,7 @@ class Cell:
     mix: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    program: ModuleType
 
 
 def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
@@ -79,25 +84,49 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
     e2e = [m for m in spec["end_to_end"] if mine(m)]
     moved = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"] if m["moves"] in moved and mine(m)]
-    return Cell(workload, int(w["chips"]), config, mix, e2e, layer)
+    program = load_program(config["program"], root)
+    return Cell(workload, int(w["chips"]), config, mix, e2e, layer, program)
+
+
+def _load(kind: str, name: str, root: pathlib.Path) -> ModuleType:
+    """Import `bench/<kind>/<name>.py` by its path; a missing file is an error naming it."""
+    path = root / "bench" / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"bench/{kind}/{name}.py not found under {root}")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name.replace('.', '_')}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_reader(metric: str, root: pathlib.Path = ROOT):
     """The `read(ctx)` function of `bench/metrics/<metric>.py`."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location("bench_metric_" + metric.replace(".", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load("metrics", metric, root).read
+
+
+def load_program(name: str, root: pathlib.Path = ROOT) -> ModuleType:
+    """The program adapter `bench/programs/<name>.py`: `config(model)`, `linear_shapes(model)`."""
+    return _load("programs", name, root)
+
+
+def load_reference(name: str, root: pathlib.Path = ROOT) -> ModuleType:
+    """The plain reference `bench/reference/<name>.py` (see `benchlib.correct`)."""
+    return _load("reference", name, root)
 
 
 @dataclasses.dataclass
 class RunContext:
-    """What a per-layer reader may read after a traced run."""
+    """What a per-layer reader may read after a traced run.
+
+    `program` is the configuration's program adapter; `width` is the
+    served clouds' channels per point, xyz and features.
+    """
 
     model: dict
+    program: ModuleType
     quant: str
     batch: int
+    width: int
     chips: int
     peaks: dict
     loop: traffic.LoopResult
@@ -116,21 +145,6 @@ def end_to_end(name: str, loop: traffic.LoopResult, setup_s: float) -> float:
     if name == "clouds_per_s":
         return loop.completed_in_window() / (loop.end - loop.start)
     raise KeyError(f"no end-to-end metric {name!r}")
-
-
-def _model_config(model: dict):
-    from repro.models.pointnet2 import PointNet2Config, SAConfig
-
-    return PointNet2Config(
-        name=model["name"], task=model["task"], n_points=model["n_points"],
-        n_classes=model["n_classes"],
-        sa=tuple(SAConfig(s["n_centroids"], s["radius"], s["nsample"], tuple(s["mlp"]))
-                 for s in model["sa"]),
-        global_mlp=tuple(model.get("global_mlp", ())),
-        fp_mlp=tuple(model.get("fp_mlp", ())),
-        head=tuple(model["head"]), preproc=model["preproc"],
-        aggregation=model["aggregation"], msp_depth=model["msp_depth"],
-    )
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *, control: bool = False,
@@ -170,9 +184,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, control: bool 
     quant = cfg["policy"]["quant"]
     served_quant = cfg["control"]["policy_quant"] if control and "policy_quant" in cfg["control"] else quant
     policy = ExecutionPolicy(quant=served_quant, **(policy_override or {}))
-    ref = correct_mod.load_reference(cfg["reference"])
+    ref = load_reference(cfg["reference"], root)
     params = jax.jit(lambda k: ref.init_params(k, model))(traffic.jax_key(seed))
-    rt = ServingRuntime(_model_config(model), params, policy=policy, devices=devices)
+    rt = ServingRuntime(cell.program.config(model), params, policy=policy, devices=devices)
     rt.warmup(policies=(policy,))
     pool = traffic.make_pool(cell.mix, seed)
     keep = correct_mod.sample_slots([len(c) for c in pool], cfg["compare"]["sample"], seed)
@@ -228,7 +242,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, control: bool 
         device["busy_s"] = xtrace.busy_s(tr)
         device["window_s"] = marks["trace_window_s"]
         peaks = work.peaks_for(dev.device_kind) if require_chip else {}
-        ctx = RunContext(model, served_quant, batch, cell.chips, peaks, loop, tr)
+        ctx = RunContext(model=model, program=cell.program, quant=served_quant, batch=batch,
+                         width=pool[0].shape[1], chips=cell.chips, peaks=peaks, loop=loop,
+                         trace=tr)
         for m in cell.per_layer:
             value = load_reader(m["name"], root)(ctx)
             if value is not None:

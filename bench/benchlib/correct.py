@@ -3,29 +3,16 @@
 The number compared is the logit gap: for each sampled request,
 max |served - reference| / max |reference| over that request's logits
 (cls: its C logits; seg: its n x C per-point logits), and the worst such
-ratio over the sample. The reference is `bench/reference/<name>.py`, run
-after the window on the same clouds with weights made anew from the seed.
+ratio over the sample. The reference is `bench/reference/<name>.py`
+(`cell.load_reference`), run after the window on the same clouds, xyz and
+per-point features, with weights made anew from the seed.
 """
 
 from __future__ import annotations
 
-import importlib.util
-import pathlib
-
 import numpy as np
 
 from benchlib import traffic
-
-BENCH = pathlib.Path(__file__).resolve().parents[1]
-
-
-def load_reference(name: str):
-    """Import `bench/reference/<name>.py` by its path."""
-    path = BENCH / "reference" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
 
 
 def sample_slots(sizes: list[int], count: int, seed: int) -> set[int]:
@@ -49,7 +36,11 @@ def gap(served: np.ndarray, ref: np.ndarray) -> float:
 
 def reference_answers(ref, model: dict, seed: int, clouds: dict[int, np.ndarray], *,
                       quant: str, passes: int, block: int) -> dict[int, np.ndarray]:
-    """Reference logits of each cloud, computed in blocks of `block` clouds."""
+    """Reference logits of each cloud, computed in blocks of `block` clouds.
+
+    Each cloud is fitted to (n_points, width) as serving fits it, where
+    width is the clouds' channels per point: xyz and any features.
+    """
     import jax
 
     params = jax.jit(lambda k: ref.init_params(k, model))(traffic.jax_key(seed))
@@ -59,7 +50,7 @@ def reference_answers(ref, model: dict, seed: int, clouds: dict[int, np.ndarray]
     out: dict[int, np.ndarray] = {}
     for lo in range(0, len(slots), block):
         part = slots[lo:lo + block]
-        fitted = np.zeros((block, n_points, 3), np.float32)
+        fitted = np.zeros((block, n_points, clouds[part[0]].shape[1]), np.float32)
         for i, s in enumerate(part):
             fitted[i] = clouds[s][ref.fit_rows(len(clouds[s]), n_points)]
         logits = np.asarray(fn(params, fitted))

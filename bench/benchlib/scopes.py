@@ -106,8 +106,9 @@ def compiled_text(accel, points) -> str | None:
 def op_scopes(ctx) -> dict[str, str] | None:
     """Instruction name -> scope path of the served program, kept on `ctx`.
 
-    The program is compiled again at the run's model, policy and batch;
-    the instruction names are those of the program that ran.
+    The program is compiled again at the run's model (through the
+    configuration's program adapter), policy, batch and cloud width; the
+    instruction names are those of the program that ran.
     """
     cached = getattr(ctx, "op_scopes", None)
     if cached is not None:
@@ -115,12 +116,11 @@ def op_scopes(ctx) -> dict[str, str] | None:
     import jax
     import jax.numpy as jnp
 
-    from benchlib.cell import _model_config
     from repro.core.accelerator import get_accelerator
     from repro.core.policy import ExecutionPolicy
 
-    accel = get_accelerator(_model_config(ctx.model), ExecutionPolicy(quant=ctx.quant))
-    points = jax.ShapeDtypeStruct((ctx.batch, ctx.model["n_points"], 3), jnp.float32)
+    accel = get_accelerator(ctx.program.config(ctx.model), ExecutionPolicy(quant=ctx.quant))
+    points = jax.ShapeDtypeStruct((ctx.batch, ctx.model["n_points"], ctx.width), jnp.float32)
     text = compiled_text(accel, points)
     if text is None:
         print("scopes: the program does not expose its served artifact", file=sys.stderr)

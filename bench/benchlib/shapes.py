@@ -3,7 +3,9 @@
 Sphere, cube surface, cylinder, cone, torus, plane, helix and cross, each
 drawn in its canonical frame, rotated uniformly at random, scaled by 0.7-1.3
 and jittered by 0.02. Every point is drawn independently, so the first n
-points of a cloud are a cloud of n points of the same shape.
+points of a cloud are a cloud of n points of the same shape. Per-point
+features (`features`) are colour-like channels drawn from a key of their
+own, so that adding them leaves every cloud's xyz as it was.
 """
 
 from __future__ import annotations
@@ -63,3 +65,18 @@ def clouds(key, count: int, n_points: int):
         return (pts + 0.02 * jax.random.normal(kj, pts.shape)).astype(jnp.float32)
 
     return jax.vmap(one)(jax.random.split(key, count))
+
+
+@functools.partial(jax.jit, static_argnames=("count", "n_points", "channels"))
+def features(key, count: int, n_points: int, channels: int):
+    """Colour-like features in [0, 1]: (count, n_points, channels) float32.
+
+    Each cloud draws one base colour; each point varies it by a normal of
+    0.1 and is clipped into [0, 1].
+    """
+    def one(k):
+        kb, kp = jax.random.split(k)
+        base = jax.random.uniform(kb, (channels,))
+        return jnp.clip(base + 0.1 * jax.random.normal(kp, (n_points, channels)), 0.0, 1.0)
+
+    return jax.vmap(one)(jax.random.split(key, count)).astype(jnp.float32)

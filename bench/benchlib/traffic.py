@@ -5,7 +5,10 @@ A mix file (`bench/traffic/<mix>.json`) holds:
 * "loop": "closed", with "clients";
 * "sizes": {"kind": "fixed", "points": n} or {"kind": "loguniform",
   "low": a, "high": b};
-* "pool": how many distinct clouds the run makes and cycles through.
+* "pool": how many distinct clouds the run makes and cycles through;
+* "features" (default 0): per-point channels beyond xyz, colour-like values
+  in [0, 1]. They come from a key of their own, so a mix's xyz is the same
+  with them or without.
 
 Every seed gets the same set of sizes (quantiles of the distribution); the
 seed draws the shapes and the order. So the work of a run does not depend
@@ -46,11 +49,16 @@ def cloud_sizes(sizes: dict, count: int) -> np.ndarray:
 
 
 def make_pool(mix: dict, seed: int) -> list[np.ndarray]:
-    """The run's distinct clouds, in the seed's order, made on the device."""
+    """The run's distinct clouds, (n, 3 + features), in the seed's order, made on the device."""
     count = int(mix["pool"])
     rng = np.random.default_rng(seed)
     sizes = rng.permutation(cloud_sizes(mix["sizes"], count))
-    pts = np.asarray(shapes.clouds(jax_key(seed), count, int(sizes.max())))
+    n_max = int(sizes.max())
+    pts = np.asarray(shapes.clouds(jax_key(seed), count, n_max))
+    channels = int(mix.get("features", 0))
+    if channels:
+        key = jax.random.fold_in(jax_key(seed), 1)
+        pts = np.concatenate([pts, np.asarray(shapes.features(key, count, n_max, channels))], -1)
     return [np.ascontiguousarray(pts[i, : sizes[i]]) for i in range(count)]
 
 

@@ -18,6 +18,11 @@ Operation counts, per call:
   each), K * nsample indices (4 B) and mask bytes (1 B) written.
 * sc_matmul: 2*M*K*N. Bytes: both operands as 16-bit codes (8-bit for
   W8A8) and the float32 product.
+
+The linears of a step are the architecture's: the configuration's program
+adapter (`bench/programs/<program>.py`) lists them with `linear_shapes`.
+The FPS and lattice counts read PointNet++'s SA stages and serve only the
+cells that run those kernels.
 """
 
 from __future__ import annotations
@@ -87,39 +92,16 @@ def lattice_calls(model: dict, batch: int) -> list[Call]:
     ]
 
 
-def linear_shapes(model: dict) -> list[tuple[int, int, int]]:
-    """(rows per cloud, fan-in, fan-out) of every linear, in forward order."""
-    shapes, rows, c_in = [], model["n_points"], 3
-    chans_per_rows = []
-    for sa in model["sa"]:
-        chans_per_rows.append((rows, [c_in] + list(sa["mlp"])))
-        rows, c_in = sa["n_centroids"], sa["mlp"][-1] + 3
-    if model["task"] == "cls":
-        chans_per_rows.append((rows, [c_in] + list(model["global_mlp"])))
-        head = [model["global_mlp"][-1]] + list(model["head"]) + [model["n_classes"]]
-        chans_per_rows.append((1, head))
-    else:
-        level_rows = [model["n_points"]] + [sa["n_centroids"] for sa in model["sa"]]
-        skips = [3] + [sa["mlp"][-1] for sa in model["sa"][:-1]]
-        c_coarse, fp = model["sa"][-1]["mlp"][-1], model["fp_mlp"]
-        for i, skip_c in enumerate(reversed(skips)):
-            cout = fp[min(i, len(fp) - 1)]
-            chans_per_rows.append((level_rows[-2 - i], [c_coarse + skip_c, cout, cout]))
-            c_coarse = cout
-        head = [c_coarse] + list(model["head"]) + [model["n_classes"]]
-        chans_per_rows.append((model["n_points"], head))
-    for r, chans in chans_per_rows:
-        shapes.extend((r, a, b) for a, b in zip(chans[:-1], chans[1:]))
-    return shapes
+def model_flops_per_cloud(linears: list[tuple[int, int, int]]) -> float:
+    """2 * rows * fan-in * fan-out summed over every linear of one cloud.
+
+    `linears` is the program adapter's `linear_shapes(model)`.
+    """
+    return float(sum(2 * r * a * b for r, a, b in linears))
 
 
-def model_flops_per_cloud(model: dict) -> float:
-    """2 * rows * fan-in * fan-out summed over every linear of one cloud."""
-    return float(sum(2 * r * a * b for r, a, b in linear_shapes(model)))
-
-
-def sc_matmul_calls(model: dict, batch: int, quant: str) -> list[Call]:
-    """One call per linear under a quantized policy; none in float mode."""
+def sc_matmul_calls(linears: list[tuple[int, int, int]], batch: int, quant: str) -> list[Call]:
+    """One call per linear of `linears` under a quantized policy; none in float mode."""
     bits = QUANT_BITS[quant]
     if bits is None:
         return []
@@ -130,7 +112,7 @@ def sc_matmul_calls(model: dict, batch: int, quant: str) -> list[Call]:
             bytes=byte * (batch * r * a + a * b) + 4 * batch * r * b,
             compute="int8_ops_per_s",
         )
-        for r, a, b in linear_shapes(model)
+        for r, a, b in linears
     ]
 
 

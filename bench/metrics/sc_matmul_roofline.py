@@ -2,7 +2,8 @@
 
 Layer kernels.sc_matmul. The least time of a call is the larger of its operations
 over the compute peak and its bytes over HBM bandwidth (`benchlib.work`);
-the bound is printed on standard error. Moves `clouds_per_s`.
+the bound is printed on standard error. One call per linear that the
+configuration's program adapter lists. Moves `clouds_per_s`.
 """
 
 import sys
@@ -17,7 +18,8 @@ def read(ctx):
     if ctx.trace is None:
         return None
     events = [e.dur * 1e-9 for e in xtrace.kernel_events(ctx.trace, KERNEL)]
-    share = work.roofline_pct(work.sc_matmul_calls(ctx.model, ctx.batch, ctx.quant), events, ctx.peaks)
+    calls = work.sc_matmul_calls(ctx.program.linear_shapes(ctx.model), ctx.batch, ctx.quant)
+    share = work.roofline_pct(calls, events, ctx.peaks)
     if share is None:
         return None
     print(f"sc_matmul_roofline: bound {share[1]}, {len(events)} {KERNEL} events", file=sys.stderr)

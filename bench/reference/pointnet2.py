@@ -15,6 +15,9 @@ no kernels, no cache, no batching across clouds. What it computes:
   distance <= 1.6 x radius, empty slots repeating the first hit).
   d is the largest depth <= `msp_depth` that keeps tiles at least four
   times their sample count with both counts divisible by 2^d.
+* input: each point is xyz and `in_features` (default 0) channels more;
+  sampling and queries read xyz alone, the first SA MLP and the finest
+  feature-propagation skip read both.
 * features with delayed aggregation: the per-point MLP on [xyz, features],
   then a gather over each neighbourhood and a masked max.
 * cls: the global MLP on [xyz, features] of the last level, a max over
@@ -85,7 +88,8 @@ def init_params(key, model: dict):
     """
     keys = iter(jax.random.split(key, 64))
     params = {"sa": []}
-    c_in = 3
+    point_c = 3 + model.get("in_features", 0)
+    c_in = point_c
     for sa in model["sa"]:
         params["sa"].append(_mlp_init(next(keys), [c_in] + list(sa["mlp"])))
         c_in = sa["mlp"][-1] + 3
@@ -96,7 +100,7 @@ def init_params(key, model: dict):
         params["head"] = _mlp_init(next(keys), head, norm=False)
         return params
     params["fp"] = []
-    skips = [3] + [sa["mlp"][-1] for sa in model["sa"][:-1]]
+    skips = [point_c] + [sa["mlp"][-1] for sa in model["sa"][:-1]]
     c_coarse = sa_out
     fp = model["fp_mlp"]
     for i, skip_c in enumerate(reversed(skips)):
@@ -280,9 +284,10 @@ def _knn3(q, r):
     return jnp.stack(idxs, 1), jnp.stack(dists, 1)
 
 
-def forward(params, model: dict, xyz, *, quant: str, passes: int = 6):
-    """Logits of one fitted cloud xyz (n_points, 3): cls (C,), seg (N, C)."""
-    levels = [(xyz, None)]
+def forward(params, model: dict, cloud, *, quant: str, passes: int = 6):
+    """Logits of one fitted cloud (n_points, 3 + in_features): cls (C,), seg (N, C)."""
+    xyz = cloud[:, :3]
+    levels = [(xyz, cloud[:, 3:] if model.get("in_features", 0) else None)]
     for sa, p in zip(model["sa"], params["sa"]):
         pts, feats = levels[-1]
         cidx, nidx, mask = sa_preprocess(pts, sa, model["msp_depth"])
@@ -303,14 +308,17 @@ def forward(params, model: dict, xyz, *, quant: str, passes: int = 6):
         w = 1.0 / (dist + 1e-8)
         w = w / w.sum(-1, keepdims=True)
         interp = (coarse_f[idx] * w[..., None]).sum(axis=1)
-        skip = fine_xyz if i == n_fp - 1 else fine_f
+        if i < n_fp - 1:
+            skip = fine_f
+        else:  # the raw points: xyz and the input features
+            skip = fine_xyz if fine_f is None else jnp.concatenate([fine_xyz, fine_f], -1)
         coarse_f = mlp(p, jnp.concatenate([interp, skip], -1), quant, passes)
         coarse_xyz = fine_xyz
     return mlp(params["head"], coarse_f, quant, passes, final_act=False)
 
 
 def make_block_fn(model: dict, *, quant: str, passes: int = 6):
-    """jit-compiled reference over a block of fitted clouds (B, N, 3)."""
+    """jit-compiled reference over a block of fitted clouds (B, N, 3 + in_features)."""
     def block(params, clouds):
         return jax.vmap(lambda c: forward(params, model, c, quant=quant, passes=passes))(clouds)
 

@@ -1,5 +1,6 @@
 """The plain reference against the program on the CPU at smoke size."""
 
+import dataclasses
 import pathlib
 import sys
 
@@ -18,7 +19,8 @@ from repro.core.accelerator import get_accelerator  # noqa: E402
 from repro.core.policy import ExecutionPolicy  # noqa: E402
 from repro.serve.pointcloud import inverse_subsample_indices, pad_cloud  # noqa: E402
 
-REF = correct.load_reference("pointnet2")
+REF = cell.load_reference("pointnet2")
+PN2 = cell.load_program("pointnet2")
 
 
 def _model(cfg, task):
@@ -26,28 +28,41 @@ def _model(cfg, task):
            "mlp": list(s.mlp)} for s in cfg.sa]
     return {"task": task, "n_points": cfg.n_points, "n_classes": cfg.n_classes, "sa": sa,
             "global_mlp": list(cfg.global_mlp), "fp_mlp": list(cfg.fp_mlp),
-            "head": list(cfg.head), "msp_depth": cfg.msp_depth}
+            "head": list(cfg.head), "msp_depth": cfg.msp_depth, "in_features": cfg.in_features}
 
 
+# (task, quant, tolerance, per-point features beyond xyz)
 CASES = [
-    ("cls", "none", 1e-5),
-    ("cls", "sc_w16a16", 1e-3),
-    ("seg", "none", 1e-5),
+    ("cls", "none", 1e-5, 0),
+    ("cls", "sc_w16a16", 1e-3, 0),
+    ("seg", "none", 1e-5, 0),
+    ("cls", "none", 1e-5, 3),
+    ("seg", "none", 1e-5, 3),
 ]
 
 
-@pytest.mark.parametrize("task,quant,tol", CASES, ids=[f"{t}-{q}" for t, q, _ in CASES])
-def test_reference_matches_program(task, quant, tol):
-    """Served logits of a smoke batch lie within the tolerance of the reference."""
+@pytest.mark.parametrize("task,quant,tol,features", CASES,
+                         ids=[f"{t}-{q}" + (f"-f{f}" if f else "") for t, q, _, f in CASES])
+def test_reference_matches_program(task, quant, tol, features):
+    """Served logits of a smoke batch, (n, 3 + features) clouds, lie within the tolerance."""
     cfg = (pointnet2_cls if task == "cls" else pointnet2_seg).smoke_config()
+    cfg = dataclasses.replace(cfg, in_features=features)
     model = _model(cfg, task)
     params = REF.init_params(jax.random.PRNGKey(1), model)
     pts = np.asarray(shapes.clouds(jax.random.PRNGKey(2), 4, cfg.n_points))
+    if features:
+        colour = shapes.features(jax.random.PRNGKey(3), 4, cfg.n_points, features)
+        pts = np.concatenate([pts, np.asarray(colour)], -1)
     accel = get_accelerator(cfg, ExecutionPolicy(quant=quant, backend="xla"))
     served = np.asarray(accel.infer(params, jnp.asarray(pts)))
     ref = np.asarray(REF.make_block_fn(model, quant=quant)(params, pts))
     for i in range(len(pts)):
         assert correct.gap(served[i], ref[i]) < tol
+    if features:  # the features reach the logits: zeroed, the reference moves far
+        no_colour = pts.copy()
+        no_colour[..., 3:] = 0.0
+        moved = np.asarray(REF.make_block_fn(model, quant=quant)(params, no_colour))
+        assert min(correct.gap(moved[i], ref[i]) for i in range(len(pts))) > 100 * tol
 
 
 def test_reference_preprocess_matches_program_bitwise():
@@ -81,7 +96,7 @@ SEG4 = {
 
 def test_four_level_seg_matches_program():
     """Four SA levels: logits within 1e-5 of the program's, preprocessing bit for bit."""
-    cfg = cell._model_config(SEG4)
+    cfg = PN2.config(SEG4)
     assert [REF.msp_depth(n, sa["n_centroids"], 3) for n, sa in
             zip([256, 64, 32, 8], SEG4["sa"])] == [3, 0, 3, 1]
     params = REF.init_params(jax.random.PRNGKey(6), SEG4)

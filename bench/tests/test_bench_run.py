@@ -5,6 +5,8 @@ program's smoke sizes; everything else is the run the benchmark makes. The
 fault cases break the timed path underneath and must read `correct` false.
 """
 
+import hashlib
+import json
 import pathlib
 import sys
 
@@ -110,3 +112,39 @@ def test_pool_is_made_from_the_seed():
     assert sorted(len(c) for c in p1) == sorted(traffic.cloud_sizes(mix["sizes"], 6))
     p3 = traffic.make_pool(mix, 2**35 + 5)
     assert not all(len(a) == len(b) and np.array_equal(a, b) for a, b in zip(p1, p3))
+
+
+# sha256 of each pool's bytes (every cloud's xyz, in pool order), taken
+# before mixes could carry features; the xyz of a mix never changes
+POOL_XYZ_SHA256 = {
+    ("modelnet-closed32", 3): "ebecba3f88e9bdee60a9877876be3b3cf93bc88f4c3f9dc3afde478340280a38",
+    ("modelnet-closed32", 2**33 + 17):
+        "67b4ffc35f44597582d0e10179ff3fd16e99608123f872ccc6a0ee1315765fd6",
+    ("s3dis-closed32", 3): "20a6e66a3d83203c69fc02f1db8c72969dab7407c031b668b81b1a0fc8f1fe97",
+    ("s3dis-closed32", 2**33 + 17):
+        "7af398b483178f7db5b9fa11fbf1a5207a1a8d946326f8aa4bea4f68628feb8d",
+}
+
+
+@pytest.mark.parametrize("mix,seed", sorted(POOL_XYZ_SHA256), ids=lambda v: str(v))
+def test_pool_xyz_is_unchanged(mix, seed):
+    """The benchmark's mixes make the same xyz, bit for bit, as before features existed."""
+    spec = json.loads((ROOT / "bench" / "traffic" / f"{mix}.json").read_text())
+    h = hashlib.sha256()
+    for cloud in traffic.make_pool(spec, seed):
+        assert cloud.shape[1] == 3
+        h.update(cloud.tobytes())
+    assert h.hexdigest() == POOL_XYZ_SHA256[(mix, seed)]
+
+
+def test_features_come_from_their_own_key():
+    """A mix's features: colour-like, in [0, 1], from the seed; its xyz as without them."""
+    mix = {"sizes": {"kind": "loguniform", "low": 16, "high": 64}, "pool": 6}
+    plain = traffic.make_pool(mix, 2**40 + 9)
+    rgb = traffic.make_pool(dict(mix, features=3), 2**40 + 9)
+    assert [c.shape for c in rgb] == [(len(c), 6) for c in plain]
+    assert all(np.array_equal(a, b[:, :3]) for a, b in zip(plain, rgb))
+    colour = np.concatenate([c[:, 3:] for c in rgb])
+    assert colour.min() >= 0.0 and colour.max() <= 1.0 and colour.std() > 0.05
+    again = traffic.make_pool(dict(mix, features=3), 2**40 + 9)
+    assert all(np.array_equal(a, b) for a, b in zip(rgb, again))

@@ -75,6 +75,24 @@ def test_op_scopes_maps_every_instruction(programs, task):
     assert names and set(scope_map) == names
 
 
+def test_op_scopes_through_the_adapter_at_the_clouds_width():
+    """The map is built from the configuration's adapter, lowered at xyz + features."""
+    from repro.configs.base import get_config
+
+    cfg = get_config("pointnet2-seg", smoke=True)
+    model = {"name": "s", "task": "seg", "n_points": cfg.n_points, "n_classes": cfg.n_classes,
+             "in_features": 3, "fp_mlp": list(cfg.fp_mlp), "head": list(cfg.head),
+             "sa": [{"n_centroids": s.n_centroids, "radius": s.radius, "nsample": s.nsample,
+                     "mlp": list(s.mlp)} for s in cfg.sa],
+             "preproc": "pc2im", "aggregation": "delayed", "msp_depth": cfg.msp_depth}
+    ctx = SimpleNamespace(program=cell.load_program("pointnet2"), model=model, quant="none",
+                          batch=2, width=6)
+    scope_map = scopes.op_scopes(ctx)
+    assert scope_map is ctx.op_scopes
+    assert any(p.startswith("fp2/knn") for p in scope_map.values())
+    assert any(p.startswith("sa1/mlp") for p in scope_map.values())
+
+
 def test_op_scopes_of_a_program_that_hides_its_artifact():
     """A program without `infer_program` (the parent's) gives no map, no error."""
     spec = jax.ShapeDtypeStruct((BATCH, 16, 3), jnp.float32)

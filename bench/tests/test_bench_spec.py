@@ -6,13 +6,16 @@ import re
 import shutil
 import subprocess
 import sys
+from types import SimpleNamespace
 
+import jax
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "bench"))
+sys.path.insert(0, str(ROOT / "src"))
 
-from benchlib import cell  # noqa: E402
+from benchlib import cell, work  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -44,11 +47,12 @@ def test_workload_resolves(w):
 
 @pytest.mark.parametrize("c", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_file(c):
-    """Each configuration file agrees with its entry and names its reference."""
+    """Each configuration file agrees with its entry and names its reference and program."""
     cfg = json.loads((ROOT / c["file"]).read_text())
     assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
     assert cfg["reduced"] == c["reduced"]
     assert (ROOT / "bench" / "reference" / f"{cfg['reference']}.py").is_file()
+    assert (ROOT / "bench" / "programs" / f"{cfg['program']}.py").is_file()
     assert 0 < cfg["compare"]["limit"] < 1
     assert any(w["config"] == c["name"] for w in SPEC["workloads"])
 
@@ -94,6 +98,127 @@ def test_new_cell_metric_and_mix_found_without_edits(tmp_path):
     assert c.mix["clients"] == 2
     assert [m["name"] for m in c.per_layer] == ["clouds_seen"]
     assert cell.load_reader("clouds_seen", tmp_path)(None) == 7.0
+
+
+# A configuration of another program: its adapter, reference, traffic with
+# colour channels and cell are new files. The test's adapter and reference
+# serve PointNet++ on xyz + rgb through the files already there.
+ADAPTER = '''"""Test adapter: PointNet++ on xyz and colour."""
+
+import importlib.util
+import pathlib
+
+_spec = importlib.util.spec_from_file_location(
+    "pn2_base", pathlib.Path(__file__).with_name("pointnet2.py"))
+_base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+
+
+def config(model):
+    """The program's configuration."""
+    return _base.config(dict(model, in_features=model["colour"]))
+
+
+def linear_shapes(model):
+    """Every linear of one cloud."""
+    return _base.linear_shapes(dict(model, in_features=model["colour"]))
+'''
+REFERENCE = '''"""Test reference: PointNet++ on xyz and colour."""
+
+import importlib.util
+import pathlib
+
+_spec = importlib.util.spec_from_file_location(
+    "pn2_ref", pathlib.Path(__file__).with_name("pointnet2.py"))
+_base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+fit_rows, output_rows = _base.fit_rows, _base.output_rows
+
+
+def init_params(key, model):
+    """Seeded weights."""
+    return _base.init_params(key, dict(model, in_features=model["colour"]))
+
+
+def make_block_fn(model, **kw):
+    """The reference over a block of clouds."""
+    return _base.make_block_fn(dict(model, in_features=model["colour"]), **kw)
+'''
+RGB_MODEL = {
+    "name": "pn2-rgb", "task": "seg", "n_points": 256, "n_classes": 13, "colour": 3,
+    "sa": [{"n_centroids": 64, "radius": 0.3, "nsample": 16, "mlp": [32, 32, 64]},
+           {"n_centroids": 16, "radius": 0.6, "nsample": 16, "mlp": [64, 64, 128]}],
+    "fp_mlp": [64, 64], "head": [64], "preproc": "pc2im", "aggregation": "delayed",
+    "msp_depth": 2,
+}
+
+
+@pytest.fixture(scope="module")
+def rgb_root(tmp_path_factory):
+    """A checkout with a third configuration, its cell, mix and files added."""
+    root = tmp_path_factory.mktemp("rgb")
+    shutil.copytree(ROOT / "bench", root / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "bench" / "programs" / "pn2_rgb.py").write_text(ADAPTER)
+    (root / "bench" / "reference" / "pn2_rgb.py").write_text(REFERENCE)
+    (root / "bench" / "traffic" / "tiny-rgb3.json").write_text(json.dumps(
+        {"loop": "closed", "clients": 4, "sizes": {"kind": "fixed", "points": 256}, "pool": 8,
+         "features": 3}))
+    (root / "bench" / "configs" / "pn2-rgb.json").write_text(json.dumps({
+        "name": "pn2-rgb", "source": "test", "program": "pn2_rgb", "reference": "pn2_rgb",
+        "model": RGB_MODEL, "policy": {"quant": "none"}, "matmul_precision": "highest",
+        "compare": {"sample": 4, "block": 4, "limit": 6e-6},
+        "control": {"reference_passes": 3}, "reduced": []}))
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "pn2-rgb", "source": "test", "reduced": [], "why": "test",
+                            "file": "bench/configs/pn2-rgb.json"})
+    spec["workloads"].append({"name": "seg-rgb-tiny", "config": "pn2-rgb",
+                              "traffic": "tiny-rgb3", "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in ("clouds_per_s", "step_mfu"):
+            m["workloads"].append("seg-rgb-tiny")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    yield root
+    jax.config.update("jax_default_matmul_precision", None)
+
+
+def _run_rgb(root):
+    result = cell.run("seg-rgb-tiny", 2**33 + 5, 1.0, False, require_chip=False,
+                      policy_override={"backend": "xla"}, compile_cache=False, root=root)
+    for path in (ROOT / "bench").rglob("*"):  # every copied file byte-identical
+        if path.is_file() and "__pycache__" not in path.parts:
+            assert (root / path.relative_to(ROOT)).read_bytes() == path.read_bytes(), path
+    return result
+
+
+def test_new_architecture_enters_as_new_files(rgb_root):
+    """The added configuration serves xyz + rgb correctly; step_mfu counts its adapter's linears."""
+    c = cell.load_cell("seg-rgb-tiny", rgb_root)
+    assert pathlib.Path(c.program.__file__) == rgb_root / "bench" / "programs" / "pn2_rgb.py"
+    assert {m["name"] for m in c.per_layer} == {"step_mfu"}
+    r = _run_rgb(rgb_root)
+    assert r["correct"], r["checks"]
+    assert r["failed"] == 0 and r["checks"]["checked"]["value"] >= 1
+    linears = c.program.linear_shapes(RGB_MODEL)
+    assert linears[0] == (256, 6, 32)
+    ctx = SimpleNamespace(program=c.program, model=RGB_MODEL, chips=1, clouds_per_s=10.0,
+                          peaks={"bf16_flops_per_s": 1e12})
+    mfu = cell.load_reader("step_mfu", rgb_root)(ctx)
+    assert mfu == pytest.approx(100.0 * work.model_flops_per_cloud(linears) * 10.0 / 1e12)
+
+
+def test_new_architecture_with_features_zeroed_reads_incorrect(rgb_root, monkeypatch):
+    """Served colour channels zeroed before the model: the comparison fails."""
+    from repro.core.accelerator import PC2IMAccelerator
+
+    orig = PC2IMAccelerator.infer
+
+    def infer(self, params, points):
+        return orig(self, params, points.at[..., 3:].set(0.0))
+
+    monkeypatch.setattr(PC2IMAccelerator, "infer", infer)
+    r = _run_rgb(rgb_root)
+    assert not r["correct"]
+    assert r["checks"]["logit_gap"]["value"] > r["checks"]["logit_gap"]["limit"]
 
 
 def _run_cli(cwd, *args):

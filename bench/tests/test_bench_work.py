@@ -8,7 +8,9 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "bench"))
 
-from benchlib import work  # noqa: E402
+from benchlib import cell, work  # noqa: E402
+
+PN2 = cell.load_program("pointnet2")
 
 PEAKS = {"bf16_flops_per_s": 200e12, "int8_ops_per_s": 400e12, "hbm_bytes_per_s": 800e9}
 
@@ -21,7 +23,6 @@ SMOKE_CLS = {
            {"n_centroids": 16, "radius": 0.6, "nsample": 16, "mlp": [64, 64, 128]}],
     "global_mlp": [128, 256], "head": [128],
 }
-SMOKE_SEG = dict(SMOKE_CLS, task="seg", fp_mlp=[64, 64], head=[64])
 
 
 def test_sa_tiles_hand_count():
@@ -39,48 +40,15 @@ def test_fps_and_lattice_hand_count():
     assert lat[1].bytes == 2 * 4 * (12 * 16 + 12 * 4 + 5 * 4 * 16)
 
 
-def test_linear_shapes_and_model_flops_cls():
-    """Every cls linear's rows and widths, and their FLOPs."""
-    shapes = work.linear_shapes(SMOKE_CLS)
-    assert shapes == [
-        (256, 3, 32), (256, 32, 32), (256, 32, 64),
-        (64, 67, 64), (64, 64, 64), (64, 64, 128),
-        (16, 131, 128), (16, 128, 256),
-        (1, 256, 128), (1, 128, 8),
-    ]
-    assert work.model_flops_per_cloud(SMOKE_CLS) == sum(2 * r * a * b for r, a, b in shapes)
-
-
-def test_linear_shapes_seg():
-    """Every seg linear's rows and widths."""
-    assert work.linear_shapes(SMOKE_SEG) == [
-        (256, 3, 32), (256, 32, 32), (256, 32, 64),
-        (64, 67, 64), (64, 64, 64), (64, 64, 128),
-        (64, 192, 64), (64, 64, 64),  # FP onto the 64 SA1 centroids: 128 + 64 skip
-        (256, 67, 64), (256, 64, 64),  # FP onto the raw points: 64 + 3 xyz
-        (256, 64, 64), (256, 64, 8),
-    ]
-
-
-def test_full_cls_model_flops():
-    """Model FLOPs of the full cls configuration by hand."""
-    full = dict(SMOKE_CLS, n_points=1024, msp_depth=2,
-                sa=[{"n_centroids": 256, "radius": 0.2, "nsample": 32, "mlp": [64, 64, 128]},
-                    {"n_centroids": 64, "radius": 0.4, "nsample": 32, "mlp": [128, 128, 256]}],
-                global_mlp=[256, 512, 1024], head=[512, 256])
-    hand = (2 * 1024 * (3 * 64 + 64 * 64 + 64 * 128) + 2 * 256 * (131 * 128 + 128 * 128 + 128 * 256)
-            + 2 * 64 * (259 * 256 + 256 * 512 + 512 * 1024) + 2 * (1024 * 512 + 512 * 256 + 256 * 8))
-    assert work.model_flops_per_cloud(full) == hand
-
-
 def test_sc_matmul_calls():
-    """SC matmul calls: none in float mode, 2*M*K*N against the int8 peak."""
-    assert work.sc_matmul_calls(SMOKE_CLS, 8, "none") == []
-    calls = work.sc_matmul_calls(SMOKE_CLS, 8, "sc_w16a16")
+    """SC matmul calls of the adapter's linears: none in float mode, 2*M*K*N against the int8 peak."""
+    linears = PN2.linear_shapes(SMOKE_CLS)
+    assert work.sc_matmul_calls(linears, 8, "none") == []
+    calls = work.sc_matmul_calls(linears, 8, "sc_w16a16")
     assert calls[0].ops == 2 * 8 * 256 * 3 * 32
     assert calls[0].bytes == 2 * (8 * 256 * 3 + 3 * 32) + 4 * 8 * 256 * 32
     assert calls[0].compute == "int8_ops_per_s"
-    assert work.sc_matmul_calls(SMOKE_CLS, 8, "sc_w8a8")[0].bytes == (
+    assert work.sc_matmul_calls(linears, 8, "sc_w8a8")[0].bytes == (
         8 * 256 * 3 + 3 * 32 + 4 * 8 * 256 * 32)
 
 
@@ -89,7 +57,7 @@ def test_roofline_names_its_bound_and_reads_100_at_the_least_time(kind):
     """Rooflines name their bound and read 100% at the least time."""
     calls = {"fps": work.fps_calls(SMOKE_CLS, 8),
              "lattice": work.lattice_calls(SMOKE_CLS, 8),
-             "sc_matmul": work.sc_matmul_calls(SMOKE_CLS, 8, "sc_w16a16")}[kind]
+             "sc_matmul": work.sc_matmul_calls(PN2.linear_shapes(SMOKE_CLS), 8, "sc_w16a16")}[kind]
     least = [c.least_s(PEAKS) for c in calls]
     assert all(b in ("bf16_flops_per_s", "int8_ops_per_s", "hbm_bytes_per_s") for _, b in least)
     events = [t for t, _ in least] * 3  # three batches, each call at its least time
